@@ -18,14 +18,20 @@ import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "src"))
+
+from repro.roofline import V5E, peaks_for  # noqa: E402
 
 _WORKER = r"""
 import os, sys, time, json
 n = int(sys.argv[1]); batch_mode = sys.argv[2]
 os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+os.environ["JAX_PLATFORMS"] = "cpu"   # forced host devices: CPU only
 import numpy as np
 import jax, jax.numpy as jnp
 import repro.core as synk
+from repro.launch.cache import enable_compile_cache
+enable_compile_cache()
 
 synk.fork()
 B = 64 if batch_mode == "fixed" else 64 * n
@@ -92,7 +98,8 @@ def derived_speedup(n: int, mode: str, n_params: int) -> float:
     per-call host overhead ~50us.  Mirrors paper Fig. 2/3 on v5e."""
     flops_1gpu = 3 * 8.2e9 * 64   # ResNet-50: 2x fwd flops x batch, fwd+bwd
     resnet_params = 25.6e6
-    peak, ici = 197e12, 50e9
+    v5e = peaks_for(V5E)
+    peak, ici = v5e.flops, v5e.ici_bw
     overlap = 0.9                 # grad all-reduce overlaps bwd compute
     batch_scale = 1.0 if mode == "fixed" else n
     t_comp = flops_1gpu * batch_scale / n / peak

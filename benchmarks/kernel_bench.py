@@ -1,15 +1,23 @@
-"""Micro-benchmarks of the Pallas kernels (interpret mode on CPU — timing
-is indicative only; the derived column reports the v5e roofline time for
-the same workload, which is what the kernel targets)."""
+"""Micro-benchmarks of the Pallas kernels (interpret mode off the chip —
+timing there is indicative only; the derived column reports the v5e
+roofline time for the same workload, which is what the kernel targets)."""
 from __future__ import annotations
 
+import os
+import sys
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-PEAK, HBM = 197e12, 819e9
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.launch.cache import enable_compile_cache  # noqa: E402
+from repro.roofline import V5E, peaks_for  # noqa: E402
+
+_V5E = peaks_for(V5E)
+PEAK, HBM = _V5E.flops, _V5E.hbm_bw
 
 
 def _timeit(fn, *args, iters=3):
@@ -23,6 +31,7 @@ def _timeit(fn, *args, iters=3):
 
 
 def main(emit) -> None:
+    enable_compile_cache()
     rng = np.random.default_rng(0)
 
     # flash attention: B1 S2048 H8 D128
@@ -63,7 +72,7 @@ def main(emit) -> None:
     gr = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
     m = jnp.zeros(n, jnp.float32)
     vv = jnp.zeros(n, jnp.float32)
-    step = jnp.array([1], jnp.int32)
+    step = jnp.int32(1)
     dt = _timeit(lambda *a: flat_adam_op(*a, lr=1e-3), p, gr, m, vv, step)
     bytes_ = 7 * n * 4
     emit("kernel/flat_adam/1M", dt * 1e6,

@@ -28,6 +28,8 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
+# the forced host devices exist only on the CPU backend: never take a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse     # noqa: E402
 import json         # noqa: E402
@@ -40,6 +42,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax          # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 
 def _median_ms(fn, iters: int, warmup: int = 2) -> float:
@@ -165,6 +169,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--iters", type=int, default=None)
     ap.add_argument("--json", default=None, help="also write JSON to this path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     iters = args.iters or (3 if args.smoke else 10)
 
     report = {

@@ -6,11 +6,15 @@ Prints ``name,us_per_call,derived`` CSV rows:
   roofline_report  — §Roofline terms per dry-run cell (this repo's tables)
   kernel_bench     — Pallas kernel micro-benchmarks
   overlap          — bucketed flat-gradient engine + dispatch overhead
-                     (subprocess on a forced 8-device host mesh; also
-                     writes BENCH_overlap.json to the repo root)
+                     (forced 8-device host mesh; also writes
+                     BENCH_overlap.json to the repo root)
   serve            — continuous-batching serve engine vs the static-batch
-                     loop on a Poisson arrival trace (subprocess, 8-device
-                     host mesh; writes BENCH_serve.json to the repo root)
+                     loop on a Poisson arrival trace (8-device host mesh;
+                     writes BENCH_serve.json to the repo root)
+
+This process never imports JAX: a chip belongs to one process at a time,
+so every phase runs as a child process, one after another.  The phases
+that fake host devices pin themselves to the CPU backend.
 """
 from __future__ import annotations
 
@@ -19,7 +23,6 @@ import json
 import os
 import subprocess
 import sys
-import traceback
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -28,25 +31,36 @@ def emit(name: str, us: float, derived: str = "") -> None:
     print(f"{name},{us:.1f},{derived}", flush=True)
 
 
-def run_overlap(emit, smoke: bool = True,
-                out_json: str | None = None) -> bool:
-    """Run overlap_bench in a subprocess (it needs XLA_FLAGS set before jax
-    initializes) and surface headline numbers as CSV rows."""
-    out_json = out_json or os.path.join(REPO, "BENCH_overlap.json")
-    cmd = [sys.executable, os.path.join(REPO, "benchmarks", "overlap_bench.py"),
-           "--json", out_json]
-    if smoke:
-        cmd.append("--smoke")
+def child(script: str, *args: str, timeout: int = 1800):
+    """Run ``benchmarks/<script>`` as a child process with ``src`` on its
+    path; returns the CompletedProcess (stdout/stderr captured)."""
     env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    r = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                       timeout=1800)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(REPO, "src")]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmarks", script), *args],
+        capture_output=True, text=True, env=env, timeout=timeout)
     if r.returncode != 0:
         print(r.stdout[-2000:], file=sys.stderr)
         print(r.stderr[-2000:], file=sys.stderr)
+    return r
+
+
+def run_csv(script: str) -> bool:
+    """A phase whose ``__main__`` prints CSV rows: pass them through."""
+    r = child(script)
+    print(r.stdout, end="", flush=True)
+    return r.returncode == 0
+
+
+def run_overlap(emit, smoke: bool = True,
+                out_json: str | None = None) -> bool:
+    """Run overlap_bench and surface headline numbers as CSV rows."""
+    out_json = out_json or os.path.join(REPO, "BENCH_overlap.json")
+    r = child("overlap_bench.py", "--json", out_json,
+              *(["--smoke"] if smoke else []))
+    if r.returncode != 0:
         return False
     with open(out_json) as fh:
         rep = json.load(fh)
@@ -63,23 +77,11 @@ def run_overlap(emit, smoke: bool = True,
 
 
 def run_serve(emit, smoke: bool = True, out_json: str | None = None) -> bool:
-    """Run serve_bench in a subprocess (XLA_FLAGS before jax init) and
-    surface the headline rows as CSV."""
+    """Run serve_bench and surface the headline rows as CSV."""
     out_json = out_json or os.path.join(REPO, "BENCH_serve.json")
-    cmd = [sys.executable, os.path.join(REPO, "benchmarks", "serve_bench.py"),
-           "--json", out_json]
-    if smoke:
-        cmd.append("--smoke")
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    r = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                       timeout=1800)
+    r = child("serve_bench.py", "--json", out_json,
+              *(["--smoke"] if smoke else []))
     if r.returncode != 0:
-        print(r.stdout[-2000:], file=sys.stderr)
-        print(r.stderr[-2000:], file=sys.stderr)
         return False
     with open(out_json) as fh:
         rep = json.load(fh)
@@ -117,39 +119,18 @@ def main() -> None:
         (args.only or "fig23,table1,roofline,kernels,overlap,serve").split(","))
 
     print("name,us_per_call,derived")
+    phases = [
+        ("overlap", lambda: run_overlap(emit, smoke=not args.full_overlap)),
+        ("serve", lambda: run_serve(emit, smoke=not args.full_overlap)),
+        ("roofline", lambda: run_csv("roofline_report.py")),
+        ("kernels", lambda: run_csv("kernel_bench.py")),
+        ("table1", lambda: run_csv("table1_profile.py")),
+        ("fig23", lambda: run_csv("fig23_scaling.py")),
+    ]
     ok = True
-    if "overlap" in want:
-        try:
-            ok = run_overlap(emit, smoke=not args.full_overlap) and ok
-        except Exception:
-            ok = False
-            traceback.print_exc()
-    if "serve" in want:
-        try:
-            ok = run_serve(emit, smoke=not args.full_overlap) and ok
-        except Exception:
-            ok = False
-            traceback.print_exc()
-    if "roofline" in want:
-        from benchmarks import roofline_report
-        roofline_report.main(emit)
-    if "kernels" in want:
-        from benchmarks import kernel_bench
-        kernel_bench.main(emit)
-    if "table1" in want:
-        from benchmarks import table1_profile
-        try:
-            table1_profile.main(emit)
-        except Exception:
-            ok = False
-            traceback.print_exc()
-    if "fig23" in want:
-        from benchmarks import fig23_scaling
-        try:
-            fig23_scaling.main(emit)
-        except Exception:
-            ok = False
-            traceback.print_exc()
+    for name, run in phases:
+        if name in want:
+            ok = run() and ok
     sys.exit(0 if ok else 1)
 
 

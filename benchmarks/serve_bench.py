@@ -141,6 +141,8 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
+# the forced host devices exist only on the CPU backend: never take a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse     # noqa: E402
 import dataclasses  # noqa: E402
@@ -153,6 +155,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax          # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 
 @dataclasses.dataclass
@@ -742,6 +746,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.configs import get_smoke_config
     from repro.launch.mesh import local_mesh

@@ -18,9 +18,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _WORKER = r"""
 import os, sys, time, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"   # forced host devices: CPU only
 import numpy as np
 import jax, jax.numpy as jnp
 import repro.core as synk
+from repro.launch.cache import enable_compile_cache
+enable_compile_cache()
 
 synk.fork()
 rng = np.random.default_rng(0)

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from . import context as ctx_mod
 
 _OPS = ("avg", "mean", "sum", "max", "min", "prod")
@@ -83,7 +82,7 @@ def _shard_mapped(op_fn, ctx: ctx_mod.SynkContext):
             return op_fn(v, daxes)
 
         return jax.jit(
-            compat.shard_map(dev, mesh=ctx.mesh, in_specs=spec, out_specs=spec)
+            jax.shard_map(dev, mesh=ctx.mesh, in_specs=spec, out_specs=spec)
         )(x)
 
     return per_leaf

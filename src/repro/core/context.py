@@ -16,9 +16,7 @@ from typing import Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro import compat
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _CURRENT: "SynkContext | None" = None
 
@@ -60,9 +58,9 @@ class SynkContext:
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
     """``jax.make_mesh`` with explicit Auto axis types (GSPMD propagation)."""
-    return compat.make_mesh(
+    return jax.make_mesh(
         tuple(shape), tuple(axes),
-        axis_types=(compat.AxisType.Auto,) * len(axes),
+        axis_types=(AxisType.Auto,) * len(axes),
     )
 
 

@@ -52,7 +52,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from . import context as ctx_mod
 from .aot import AotCache
 from .data import DeviceDataset, SynkData, is_dataset, is_host_data
@@ -369,7 +368,7 @@ class SynkFunction:
         # reduce below (paper semantics).  With VMA tracking on, jax.grad of
         # a replicated input inside shard_map auto-inserts a psum (the
         # pbroadcast transpose), silently pre-reducing user gradients.
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             device_fn, mesh=ctx.mesh, in_specs=tuple(in_specs),
             out_specs=out_pspecs, check_vma=False,
         )

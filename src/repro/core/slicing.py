@@ -20,7 +20,6 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from .specs import Reduce
 
 
@@ -91,7 +90,7 @@ def sliced_call(
     def _vary(x):
         # Inside shard_map, carries must match the per-slice outputs' varying
         # manual axes (data-derived values vary over the data axes).
-        return compat.pvary(x, vary_axes) if vary_axes else x
+        return jax.lax.pcast(x, vary_axes, to="varying") if vary_axes else x
 
     acc_init = [
         _vary(_acc_init(sd, op.op)) if op.op in ("mean", "sum", "max", "min") else None

@@ -30,9 +30,7 @@ def _fwd_kernel(
     causal: bool, window: int, softcap: float, scale: float,
 ):
     qi = pl.program_id(2)
-    # unit slices (not bare ints): bare-int ref indices don't normalize on
-    # older Pallas interpret mode
-    q = q_ref[pl.ds(0, 1), pl.ds(0, 1)][0, 0].astype(jnp.float32) * scale  # (bq, d)
+    q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, d)
     d = q.shape[-1]
 
     q_start = qi * bq
@@ -51,12 +49,8 @@ def _fwd_kernel(
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(
-            k_ref, (pl.ds(0, 1), pl.ds(0, 1), pl.ds(j * bk, bk), slice(None))
-        )[0, 0].astype(jnp.float32)
-        v = pl.load(
-            v_ref, (pl.ds(0, 1), pl.ds(0, 1), pl.ds(j * bk, bk), slice(None))
-        )[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        v = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -85,7 +79,7 @@ def _fwd_kernel(
     a0 = jnp.zeros((bq, d), jnp.float32)
     m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
     out = acc / jnp.maximum(l, 1e-30)[:, None]
-    o_ref[pl.ds(0, 1), pl.ds(0, 1)] = out.astype(o_ref.dtype)[None, None]
+    o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def flash_attention_fwd(

@@ -31,24 +31,18 @@ def _paged_kernel(
     len_ref, tab_ref, q_ref, k_ref, v_ref, o_ref, *,
     bs: int, nb: int, window: int, softcap: float, scale: float,
 ):
-    # unit slices (not bare ints): bare-int ref indices don't normalize on
-    # older Pallas interpret mode
-    q = q_ref[pl.ds(0, 1), pl.ds(0, 1)][0, 0].astype(jnp.float32) * scale
+    q = q_ref[0, 0].astype(jnp.float32) * scale
     rep, d = q.shape
-    length = pl.load(len_ref, (pl.ds(0, 1), pl.ds(0, 1)))[0, 0]
+    length = len_ref[0, 0]
 
     # blocks this lane attends: positions [0, length] -> length//bs + 1
     hi = jnp.minimum(length // bs + 1, nb)
 
     def body(j, carry):
         m, l, acc = carry
-        blk = pl.load(tab_ref, (pl.ds(0, 1), pl.ds(j, 1)))[0, 0]
-        k = pl.load(
-            k_ref, (pl.ds(blk, 1), pl.ds(0, bs), pl.ds(0, 1), slice(None))
-        )[0, :, 0].astype(jnp.float32)                       # (bs, d)
-        v = pl.load(
-            v_ref, (pl.ds(blk, 1), pl.ds(0, bs), pl.ds(0, 1), slice(None))
-        )[0, :, 0].astype(jnp.float32)
+        blk = tab_ref[0, j]
+        k = k_ref[blk, :, 0, :].astype(jnp.float32)          # (bs, d)
+        v = v_ref[blk, :, 0, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -75,7 +69,7 @@ def _paged_kernel(
     a0 = jnp.zeros((rep, d), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, a0))
     out = acc / jnp.maximum(l, 1e-30)[:, None]
-    o_ref[pl.ds(0, 1), pl.ds(0, 1)] = out.astype(o_ref.dtype)[None, None]
+    o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_fwd(

@@ -31,14 +31,11 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *,
     state_ref[...] = jnp.zeros_like(state_ref)
 
     def chunk_step(ci, _):
-        # unit slices (not bare ints): bare-int ref indices don't normalize
-        # on older Pallas interpret mode
-        u = pl.ds(0, 1)
         sl = pl.ds(ci * chunk, chunk)
-        x = pl.load(x_ref, (u, u, sl, slice(None)))[0, 0].astype(jnp.float32)   # (Q,P)
-        dt = pl.load(dt_ref, (u, u, sl))[0, 0].astype(jnp.float32)              # (Q,)
-        Bm = pl.load(b_ref, (u, u, sl, slice(None)))[0, 0].astype(jnp.float32)  # (Q,N)
-        Cm = pl.load(c_ref, (u, u, sl, slice(None)))[0, 0].astype(jnp.float32)
+        x = x_ref[0, 0, sl, :].astype(jnp.float32)       # (Q,P)
+        dt = dt_ref[0, 0, sl].astype(jnp.float32)        # (Q,)
+        Bm = b_ref[0, 0, sl, :].astype(jnp.float32)      # (Q,N)
+        Cm = c_ref[0, 0, sl, :].astype(jnp.float32)
 
         la = dt * A                                      # (Q,) log decay
         cum = jnp.cumsum(la)                             # inclusive
@@ -71,7 +68,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *,
             preferred_element_type=jnp.float32,
         )
         state_ref[...] = S_new
-        pl.store(o_ref, (u, u, sl, slice(None)), y.astype(o_ref.dtype)[None, None])
+        o_ref[0, 0, sl, :] = y.astype(o_ref.dtype)
         return ()
 
     jax.lax.fori_loop(0, nc, chunk_step, ())

@@ -25,7 +25,7 @@ from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
 from repro.launch.mesh import make_production_mesh
 from repro.models.common import ShardRules
 from repro.optim import OptConfig
-from repro.roofline import summarize_cell
+from repro.roofline import V5E, summarize_cell
 from repro.serve.step import jit_decode_step, jit_prefill
 from repro.train.step import TrainSettings, jit_train_step
 
@@ -155,7 +155,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
     cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
-    out = summarize_cell(cfg, shape, cost, mem, hlo, n_chips)
+    out = summarize_cell(cfg, shape, cost, mem, hlo, n_chips, V5E)
     out.update({
         "mesh": "multi" if multi_pod else "single",
         "faithful": faithful,

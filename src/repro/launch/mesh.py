@@ -8,13 +8,11 @@ import and then calls it.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    return make_mesh(
+    return jax.make_mesh(
         tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
     )
 

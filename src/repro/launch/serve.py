@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import local_mesh, make_production_mesh, single_device_mesh
 from repro.models import registry
 from repro.models.common import ShardRules
@@ -317,6 +318,7 @@ def main():
                          "evict-and-requeue the lowest-priority lane when "
                          "decode growth finds it empty (paged only)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = {"production": make_production_mesh,
             "local": local_mesh,

@@ -17,6 +17,7 @@ import jax
 
 from repro.configs import ARCH_IDS, SHAPES, get_config, get_smoke_config
 from repro.configs.base import ShapeConfig
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import local_mesh, make_production_mesh, single_device_mesh
 from repro.models.common import ShardRules
 from repro.obs import Observer, Tracer, to_chrome_trace
@@ -54,6 +55,7 @@ def main():
                          "stop_trace (device-side TensorBoard/Perfetto "
                          "trace); independent of --trace-out")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mesh == "production":
         mesh = make_production_mesh()
@@ -76,13 +78,8 @@ def main():
         rules = dataclasses.replace(rules, sp=False)
 
     obs = Observer(tracer=Tracer(), name="train") if args.trace_out else None
-    profiling = False
     if args.jax_profile:
-        try:
-            jax.profiler.start_trace(args.jax_profile)
-            profiling = True
-        except Exception as e:  # noqa: BLE001 - profiler is optional
-            print(f"# jax profiler unavailable ({e}); continuing untraced")
+        jax.profiler.start_trace(args.jax_profile)
 
     res = train(
         cfg, shape, mesh, rules,
@@ -93,7 +90,7 @@ def main():
         obs=obs,
     )
 
-    if profiling:
+    if args.jax_profile:
         jax.profiler.stop_trace()
         print(f"# jax profile written to {args.jax_profile}")
     if obs is not None:

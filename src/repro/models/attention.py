@@ -19,7 +19,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -318,7 +317,7 @@ def decode_attention(
         P(b, s_sp, None, None),
         P(b, s_sp, None, None),
     )
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 # ---------------------------------------------------------------------------
 # Sharding rules
@@ -154,7 +153,7 @@ def wuse(w, rules: ShardRules, *logical: str | None, dtype=None):
         # the barrier stops the backend from eliding/hoisting the cast above
         # the FSDP all-gather (XLA:CPU legalizes bf16 dots to f32 and would
         # otherwise gather fp32 weights — 2x wire)
-        w = compat.optimization_barrier(w.astype(dtype))
+        w = jax.lax.optimization_barrier(w.astype(dtype))
     return constrain(w, rules, *logical)
 
 

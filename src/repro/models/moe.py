@@ -28,7 +28,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -149,7 +148,7 @@ def moe_ffn(
         P(tp, None, fsdp),                             # w_down (E, F, D)
     )
     out_specs = (P(dp or None, seq_spec, None), P(), P())
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

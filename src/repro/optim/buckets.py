@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from .flat import FlatLayout, flat_adam_update
 
 DEFAULT_BUCKET_BYTES = 4 << 20  # ~4 MiB, the NCCL-era sweet spot
@@ -50,26 +49,24 @@ _AUTO_MIN_BYTES = 1 << 20
 _AUTO_MAX_BYTES = 64 << 20
 
 
-def resolve_bucket_bytes(bucket_mb, *, group_size: int = 1) -> int:
+def resolve_bucket_bytes(bucket_mb, *, group_size: int = 1,
+                         device_kind: str | None = None) -> int:
     """Resolve ``OptConfig.bucket_mb`` (a float MiB or ``"auto"``) to bytes.
 
-    ``"auto"`` sizes buckets from the roofline model: an all-reduce over a
-    ring of ``group_size`` workers moves ``2(g-1)/g * b`` wire bytes and
-    pays a fixed per-collective latency ``ICI_LATENCY_S``; the smallest
+    ``"auto"`` sizes buckets from the roofline model of ``device_kind``
+    (``roofline.analysis.PEAKS``; an unknown kind raises): an all-reduce
+    over a ring of ``group_size`` workers moves ``2(g-1)/g * b`` wire bytes
+    and pays a fixed per-collective latency ``ici_latency_s``; the smallest
     bucket whose wire time keeps that latency under
     ``_AUTO_LATENCY_FRACTION`` of the total maximizes overlap granularity
-    without drowning in launch tax.  When the roofline lacks interconnect
-    numbers (``ICI_BW``/``ICI_LATENCY_S`` unset), auto falls back to the
-    static ~4 MiB default.
+    without drowning in launch tax.
     """
     if bucket_mb != "auto":
         return int(float(bucket_mb) * (1 << 20))
-    from repro.roofline import analysis
+    from repro.roofline.analysis import peaks_for
 
-    bw = getattr(analysis, "ICI_BW", None)
-    lat = getattr(analysis, "ICI_LATENCY_S", None)
-    if not bw or not lat:
-        return DEFAULT_BUCKET_BYTES
+    peaks = peaks_for(device_kind)
+    bw, lat = peaks.ici_bw, peaks.ici_latency_s
     g = max(int(group_size), 2)   # wire factor of a degenerate group ~ g=2
     wire_factor = 2.0 * (g - 1) / g
     # lat <= f * (lat + wire_factor*b/bw)  =>  b >= lat*(1-f)/f * bw/wire_factor
@@ -198,7 +195,7 @@ def bucketed_reduce_scatter(buf, buckets: BucketLayout, axes, op: str = "mean"):
     for part, size, pad_to in zip(_slices(buf, buckets), buckets.sizes, buckets.padded):
         if pad_to != size:
             part = jnp.concatenate([part, jnp.zeros((pad_to - size,), part.dtype)])
-        piece = compat.psum_scatter(part, axes, tiled=True)
+        piece = jax.lax.psum_scatter(part, axes, tiled=True)
         if op == "mean":
             piece = piece / n
         pieces.append(piece)
@@ -330,7 +327,7 @@ def flat_adam_apply(p, g, m, v, step, *, lr, beta1, beta2, eps,
         from repro.kernels.flat_adam.kernel import flat_adam
 
         return flat_adam(
-            p, g, m, v, jnp.reshape(step, (1,)).astype(jnp.int32),
+            p, g, m, v, step,
             lr=lr, beta1=beta1, beta2=beta2, eps=eps, weight_decay=weight_decay,
         )
     p_new, m_new, v_new = flat_adam_update(

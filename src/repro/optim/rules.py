@@ -32,9 +32,9 @@ class OptConfig:
     chunked: bool = False
     # flat-gradient bucket size (MiB) for the bucketed collective engine
     # (optim/buckets.py); parameter-boundary-aligned greedy partition.
-    # "auto" sizes buckets from the roofline interconnect model
-    # (optim/buckets.resolve_bucket_bytes), falling back to 4 MiB when the
-    # roofline lacks interconnect numbers.
+    # "auto" sizes buckets from the interconnect model of the mesh's chip
+    # (optim/buckets.resolve_bucket_bytes); a chip missing from the peak
+    # table (roofline.analysis.PEAKS) raises.
     bucket_mb: float | str = 4.0
 
     def __post_init__(self):

@@ -1,11 +1,10 @@
 from .analysis import (
-    HBM_BW, ICI_BW, PEAK_FLOPS,
-    CollectiveStats, collective_summary, model_flops, parse_collectives,
-    roofline_terms, summarize_cell,
+    PEAKS, V5E, ChipPeaks, CollectiveStats, collective_summary, model_flops,
+    parse_collectives, peaks_for, roofline_terms, summarize_cell,
 )
 
 __all__ = [
-    "HBM_BW", "ICI_BW", "PEAK_FLOPS",
-    "CollectiveStats", "collective_summary", "model_flops",
-    "parse_collectives", "roofline_terms", "summarize_cell",
+    "PEAKS", "V5E", "ChipPeaks", "CollectiveStats", "collective_summary",
+    "model_flops", "parse_collectives", "peaks_for", "roofline_terms",
+    "summarize_cell",
 ]

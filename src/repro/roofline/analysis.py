@@ -1,6 +1,6 @@
 """Roofline analysis from compiled XLA artifacts (no hardware needed).
 
-Terms (per device, TPU v5e):
+Terms (per device, peaks from :data:`PEAKS` by ``device_kind``):
     compute    = HLO_FLOPs_per_device / peak_FLOPs
     memory     = HLO_bytes_per_device / HBM_bw
     collective = wire_bytes_per_device / ICI_bw
@@ -19,15 +19,39 @@ from typing import Any
 
 import numpy as np
 
-# TPU v5e constants (task spec)
-PEAK_FLOPS = 197e12           # bf16 FLOP/s per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (use 1 link conservatively)
-# Fixed per-collective cost (launch + ring setup + per-hop latency), used by
-# the bucket-size autotuner (optim/buckets.resolve_bucket_bytes).  Set to
-# None on parts where it isn't characterized — consumers must fall back to
-# their static defaults.
-ICI_LATENCY_S = 2e-6
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float                  # bf16 FLOP/s per chip
+    hbm_bw: float                 # bytes/s per chip
+    ici_bw: float                 # bytes/s per link (one link, conservatively)
+    # fixed per-collective cost (launch + ring setup + per-hop latency) for
+    # the bucket-size autotuner (optim/buckets.resolve_bucket_bytes)
+    ici_latency_s: float
+
+
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of
+# interconnect = 4 links of 50 GB/s); the 2 us collective latency is a model
+# assumption, not a measurement.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                             ici_latency_s=2e-6),
+}
+# v5e's device_kind; the chip the production dry-run (launch/dryrun.py)
+# plans for
+V5E = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peak table entry for ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device kind {device_kind!r} (known: "
+            f"{sorted(PEAKS)}); add its published numbers to "
+            "repro.roofline.analysis.PEAKS") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -145,13 +169,14 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n * shape.global_batch
 
 
-def roofline_terms(cost: dict, coll_total_bytes: int, *, n_chips: int) -> dict:
+def roofline_terms(cost: dict, coll_total_bytes: int, *, n_chips: int,
+                   peaks: ChipPeaks) -> dict:
     """cost: compiled.cost_analysis() of the per-device SPMD module."""
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_total_bytes / ICI_BW
+    t_compute = flops_dev / peaks.flops
+    t_memory = bytes_dev / peaks.hbm_bw
+    t_coll = coll_total_bytes / peaks.ici_bw
     terms = {"compute_s": t_compute, "memory_s": t_memory, "collective_s": t_coll}
     dom = max(terms, key=terms.get)
     terms["dominant"] = dom
@@ -163,26 +188,32 @@ def roofline_terms(cost: dict, coll_total_bytes: int, *, n_chips: int) -> dict:
     return terms
 
 
-def summarize_cell(cfg, shape, cost: dict, mem, hlo_text: str, n_chips: int) -> dict:
-    """Roofline summary; FLOPs/bytes/collectives from the trip-count-aware
-    static HLO analysis (hlo_cost.py — ``cost_analysis()`` counts while
-    bodies once, so scans would be undercounted by their trip counts)."""
+def summarize_cell(cfg, shape, cost: dict, mem, hlo_text: str, n_chips: int,
+                   device_kind: str) -> dict:
+    """Roofline summary for ``n_chips`` of ``device_kind``; FLOPs/bytes/
+    collectives from the trip-count-aware static HLO analysis (hlo_cost.py —
+    ``cost_analysis()`` counts while bodies once, so scans would be
+    undercounted by their trip counts)."""
     from .hlo_cost import analyze
 
+    peaks = peaks_for(device_kind)
     hc = analyze(hlo_text)
     csum = hc.collective_summary()
     exact = {"flops": hc.flops, "bytes accessed": hc.bytes}
-    terms = roofline_terms(exact, csum["total_wire_bytes"], n_chips=n_chips)
-    terms["collective_s_bf16norm"] = csum["total_wire_bytes_bf16norm"] / ICI_BW
+    terms = roofline_terms(exact, csum["total_wire_bytes"], n_chips=n_chips,
+                           peaks=peaks)
+    terms["collective_s_bf16norm"] = \
+        csum["total_wire_bytes_bf16norm"] / peaks.ici_bw
     mf = model_flops(cfg, shape)
     hlo_total = terms["flops_per_device"] * n_chips
     terms["model_flops_total"] = mf
     terms["useful_flops_ratio"] = mf / hlo_total if hlo_total else 0.0
     # roofline fraction: useful-FLOPs time / achievable bound
-    t_useful = mf / n_chips / PEAK_FLOPS
+    t_useful = mf / n_chips / peaks.flops
     terms["roofline_fraction"] = t_useful / terms["bound_s"] if terms["bound_s"] else 0.0
     out = {
         "arch": cfg.name,
+        "device_kind": device_kind,
         "shape": shape.name,
         "kind": shape.kind,
         "n_chips": n_chips,

@@ -1047,7 +1047,11 @@ class ServeEngine:
             or any(s is not None for s in self.slots)
 
     def _put(self, x, dtype):
-        return jax.device_put(jnp.asarray(x, dtype), self._rep)
+        # np.array copies: on CPU a suitably aligned host buffer is aliased
+        # zero-copy, and the host mirrors (tables, tokens, active, ...) are
+        # mutated in place while an executable reading them may still be
+        # queued behind async dispatch
+        return jax.device_put(np.array(x, dtype=dtype), self._rep)
 
     # -- paged block bookkeeping ---------------------------------------
     def _can_admit(self, req: _Pending) -> bool:

@@ -117,7 +117,6 @@ def check_bucketed_reduce():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.optim.buckets import (
         bucketed_all_gather, bucketed_all_reduce, bucketed_reduce_scatter,
         make_buckets,
@@ -143,7 +142,7 @@ def check_bucketed_reduce():
         ag = bucketed_all_gather(rs, buckets, "data")
         return mono, buck, ag
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         worker, mesh=mesh, in_specs=P("data"), out_specs=(P(), P(), P()),
         check_vma=False,
     ))

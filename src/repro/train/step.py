@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.models import registry
 from repro.models import common as common_mod
@@ -234,7 +233,9 @@ def buckets_for(
     layout = flat_layout_for(cfg)
     return make_buckets(
         layout,
-        bucket_bytes=resolve_bucket_bytes(opt.bucket_mb, group_size=n_shards),
+        bucket_bytes=resolve_bucket_bytes(
+            opt.bucket_mb, group_size=n_shards,
+            device_kind=mesh.devices.flat[0].device_kind),
         n_shards=n_shards,
     )
 
@@ -246,7 +247,9 @@ def _build_flat_train_step(cfg, mesh, rules, opt, settings, mode: str):
     layout = flat_layout_for(cfg)
     buckets = make_buckets(
         layout,
-        bucket_bytes=resolve_bucket_bytes(opt.bucket_mb, group_size=n_data),
+        bucket_bytes=resolve_bucket_bytes(
+            opt.bucket_mb, group_size=n_data,
+            device_kind=mesh.devices.flat[0].device_kind),
         n_shards=n_data if mode == "zero" else 1,
     )
     wd = opt.weight_decay if opt.kind == "adamw" else 0.0
@@ -344,7 +347,7 @@ def _build_flat_train_step(cfg, mesh, rules, opt, settings, mode: str):
         opt_in = {"step": P(), "m": P(daxes), "v": P(daxes)}
         opt_out = {"step": P(), "m": P(daxes), "v": P(daxes)}
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         worker,
         mesh=mesh,
         in_specs=(P(), opt_in, P(daxes)),

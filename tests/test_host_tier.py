@@ -138,7 +138,7 @@ def apply_op(eng, op, rng):
         eng.clock.t += 5.0
 
 
-@settings(max_examples=4)
+@settings(max_examples=4, deadline=None)
 @given(layout=st.sampled_from(sorted(LAYOUTS)),
        seed=st.integers(0, 10_000),
        ops=st.lists(st.sampled_from(

@@ -87,7 +87,8 @@ def test_ssd_sweep(dtype, T, H, P, G, N, chunk):
         np.asarray(y, np.float32), np.asarray(ref, np.float32), **tol)
 
 
-@pytest.mark.parametrize("n,block", [(1024, 256), (4096, 4096), (512, 64)])
+@pytest.mark.parametrize("n,block", [(1024, 256), (4096, 4096), (512, 64),
+                                     (1000, 256)])   # ragged last tile
 @pytest.mark.parametrize("wd", [0.0, 0.01])
 def test_flat_adam_sweep(n, block, wd):
     rng = np.random.default_rng(n)

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.optim.buckets import (
-    DEFAULT_BUCKET_BYTES, bucketed_all_reduce, flat_adam_apply, make_buckets,
+    bucketed_all_reduce, flat_adam_apply, make_buckets,
     resolve_bucket_bytes,
 )
 from repro.optim.flat import (
@@ -93,28 +93,27 @@ def test_buckets_validation():
 
 
 def test_resolve_bucket_bytes_numeric_and_auto():
+    from repro.roofline import V5E
+
     assert resolve_bucket_bytes(4.0) == 4 << 20
     assert resolve_bucket_bytes(0.05) == int(0.05 * (1 << 20))
     # auto: roofline-derived, positive, clamped to [1, 64] MiB
-    b8 = resolve_bucket_bytes("auto", group_size=8)
+    b8 = resolve_bucket_bytes("auto", group_size=8, device_kind=V5E)
     assert (1 << 20) <= b8 <= (64 << 20)
     # bigger groups -> wire factor grows -> buckets no larger
-    assert resolve_bucket_bytes("auto", group_size=256) <= b8
+    assert resolve_bucket_bytes(
+        "auto", group_size=256, device_kind=V5E) <= b8
+    # a chip missing from the peak table is an error, not a default
+    with pytest.raises(ValueError, match="no peaks"):
+        resolve_bucket_bytes("auto", group_size=8, device_kind="cpu")
 
 
-def test_resolve_bucket_bytes_auto_falls_back_without_roofline(monkeypatch):
-    """When the roofline lacks interconnect numbers, 'auto' keeps the
-    static ~4 MiB default."""
-    from repro.roofline import analysis
-
-    monkeypatch.setattr(analysis, "ICI_LATENCY_S", None)
-    assert resolve_bucket_bytes("auto", group_size=8) == DEFAULT_BUCKET_BYTES
-
-
-def test_optconfig_bucket_mb_auto_builds_train_step():
-    """OptConfig(bucket_mb='auto') resolves through the train-step builder."""
+def test_optconfig_bucket_mb_auto_builds_train_step(monkeypatch):
+    """OptConfig(bucket_mb='auto') resolves through the train-step builder
+    from the mesh's device kind; a kind without peaks raises."""
     from repro.configs import get_smoke_config
     from repro.optim import OptConfig
+    from repro.roofline import V5E, analysis
     from repro.train.step import TrainSettings, build_train_step
     from repro.models.common import ShardRules
 
@@ -122,10 +121,14 @@ def test_optconfig_bucket_mb_auto_builds_train_step():
     cfg = get_smoke_config("smollm-360m")
     opt = OptConfig(kind="adam", lr=1e-3, bucket_mb="auto")
     rules = ShardRules.for_mesh(mesh, faithful=True)
+    with pytest.raises(ValueError, match="no peaks"):
+        build_train_step(cfg, mesh, rules, opt, TrainSettings(faithful=True))
+    kind = mesh.devices.flat[0].device_kind
+    monkeypatch.setitem(analysis.PEAKS, kind, analysis.PEAKS[V5E])
     step = build_train_step(cfg, mesh, rules, opt, TrainSettings(faithful=True))
     assert step._flat_engine == "faithful"
     assert step._flat_buckets.bucket_bytes == resolve_bucket_bytes(
-        "auto", group_size=1)
+        "auto", group_size=1, device_kind=V5E)
     with pytest.raises(ValueError):
         OptConfig(bucket_mb="bogus")
     with pytest.raises(ValueError):
@@ -136,14 +139,13 @@ def test_bucketed_all_reduce_single_axis_identity():
     """On a 1-device axis the bucketed reduce is exact slicing+concat."""
     mesh = jax.make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
-    from repro import compat
 
     layout = make_layout(_tree())
     buckets = make_buckets(layout, bucket_bytes=512)
     buf = jnp.asarray(np.random.default_rng(2).normal(size=(layout.total,)),
                       jnp.float32)
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda b: bucketed_all_reduce(b, buckets, "data"),
         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
     ))

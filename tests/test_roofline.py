@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.roofline.analysis import model_flops, roofline_terms
+from repro.roofline.analysis import (
+    V5E, model_flops, peaks_for, roofline_terms,
+)
 from repro.roofline.hlo_cost import analyze
 
 ONE_MM = 2 * 128 * 128 * 128
@@ -92,11 +94,23 @@ def test_model_flops_accounting():
 
 
 def test_roofline_terms_dominance():
-    t = roofline_terms({"flops": 197e12, "bytes accessed": 1e9}, 0, n_chips=1)
+    v5e = peaks_for(V5E)
+    t = roofline_terms({"flops": 197e12, "bytes accessed": 1e9}, 0, n_chips=1,
+                       peaks=v5e)
     assert t["dominant"] == "compute_s"
     assert t["compute_s"] == pytest.approx(1.0)
-    t = roofline_terms({"flops": 1e9, "bytes accessed": 819e9}, 0, n_chips=1)
+    t = roofline_terms({"flops": 1e9, "bytes accessed": 819e9}, 0, n_chips=1,
+                       peaks=v5e)
     assert t["dominant"] == "memory_s"
-    t = roofline_terms({"flops": 0, "bytes accessed": 0}, 50e9, n_chips=1)
+    t = roofline_terms({"flops": 0, "bytes accessed": 0}, 50e9, n_chips=1,
+                       peaks=v5e)
     assert t["dominant"] == "collective_s"
     assert t["bound_s"] == pytest.approx(1.0)
+
+
+def test_peaks_for_unknown_device_raises():
+    """The peak table is keyed by device kind; an unknown kind is an
+    error, not a default."""
+    assert peaks_for(V5E).flops == 197e12
+    with pytest.raises(ValueError, match="no peaks"):
+        peaks_for("cpu")

@@ -99,6 +99,23 @@ def test_admit_evict_invariants(setup):
         assert all(0 <= t < cfg.vocab for t in c.tokens)
 
 
+def test_put_copies_host_mirrors(setup):
+    """A pushed host mirror must not alias the device array: the engine
+    mutates its mirrors in place while a queued executable may still read
+    the pushed copy.  On CPU, a 64-byte-aligned host buffer is taken
+    zero-copy unless ``_put`` copies it."""
+    cfg, mesh, rules, params = setup
+    eng = ServeEngine(cfg, mesh, rules, params,
+                      EngineConfig(max_slots=3, max_len=32))
+    for dtype in (np.int32, np.float32, np.bool_):
+        buf = np.zeros(64 + 64, dtype)
+        off = (-buf.ctypes.data % 64) // buf.itemsize
+        mirror = buf[off: off + 64]
+        dev = eng._put(mirror, dtype)
+        mirror[:] = 1
+        assert not np.asarray(dev).any(), dtype
+
+
 def test_staggered_matches_solo_static(setup):
     """THE continuous-batching correctness property: a request admitted
     mid-flight into a slot (at its own cache position, prompt padded to a

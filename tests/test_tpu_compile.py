@@ -1,0 +1,84 @@
+"""Compile rehearsal: the main-path Pallas kernels at real widths, compiled
+for a described TPU v5e (no chip needed; the TPU compiler ships with
+jaxlib).  Catches what interpret mode cannot: unaligned blocks, VMEM
+overruns, ops Mosaic cannot lower.
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, so under several test workers only
+the worker running this file loads it.  Kernels the compiler still refuses
+at these widths (paged attention, SSD) are recorded in ROADMAP.md.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.flat_adam.kernel import flat_adam
+from repro.kernels.rmsnorm.kernel import rmsnorm, rmsnorm_add
+from repro.optim.buckets import make_buckets
+from repro.train.step import flat_layout_for
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # entries compiled for a described chip cannot be read back without one
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _compile(fn, *sds, donate=()):
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*sds).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n_shards,length", [
+    (1, 361_821_184),       # faithful: the whole flat buffer
+    (4, 90_455_296),        # ZeRO on four chips: one shard, 4 MiB buckets
+])
+def test_flat_adam_compiles_at_smollm_flat_length(one_chip, n_shards, length):
+    layout = flat_layout_for(get_config("smollm-360m"))
+    buckets = make_buckets(layout, n_shards=n_shards)
+    assert buckets.local_total == length
+    vec = jax.ShapeDtypeStruct((length,), jnp.float32, sharding=one_chip)
+    step = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile(
+        lambda p, g, m, v, t: flat_adam(p, g, m, v, t, lr=1e-3,
+                                        interpret=False),
+        vec, vec, vec, vec, step, donate=(0, 2, 3))
+    # p', m', v' are written in place of the donated p, m, v (the
+    # compiler counts padded allocations, so at least their bytes)
+    assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * length
+
+
+@pytest.mark.parametrize("fused_add", [False, True])
+def test_rmsnorm_compiles_at_d960(one_chip, fused_add):
+    x = jax.ShapeDtypeStruct((8 * 512, 960), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((960,), jnp.float32, sharding=one_chip)
+    if fused_add:
+        _compile(lambda x, r, g: rmsnorm_add(x, r, g, interpret=False),
+                 x, x, g)
+    else:
+        _compile(lambda x, g: rmsnorm(x, g, interpret=False), x, g)
+
+
+def test_flash_attention_compiles_at_smollm_heads(one_chip):
+    cfg = get_config("smollm-360m")
+    S, D = 2048, cfg.head_dim
+    q = jax.ShapeDtypeStruct((1, cfg.n_heads, S, D), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, cfg.n_kv, S, D), jnp.bfloat16,
+                              sharding=one_chip)
+    _compile(lambda q, k, v: flash_attention_fwd(q, k, v, interpret=False),
+             q, kv, kv)
