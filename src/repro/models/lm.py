@@ -177,24 +177,28 @@ def _block_fwd(cfg, mesh, rules, x, bp, positions, *, window: int,
     h = constrain(h, rules, "dp", "sp", None)
     q, k, v = _attn_proj(cfg, mesh, rules, h, bp, positions)
     extra = None
-    if attn_fn is not None:
-        attn, extra = attn_fn(q, k, v, window)
-    elif cfg.attn_impl == "pallas":
-        # TPU hot-spot path: fused flash kernel with dynamic block skipping
-        # (validated against chunked_attention in tests/test_kernels.py)
-        from repro.kernels import flash_attention
-        attn = flash_attention(
-            q, k, v, causal=True, window=window, softcap=cfg.attn_softcap,
-        )
-    else:
-        attn = chunked_attention(
-            q, k, v,
-            causal=True,
-            window=window,
-            softcap=cfg.attn_softcap,
-            q_chunk=min(256, q.shape[1]),
-            kv_chunk=min(256, k.shape[1]),
-        )
+    # the attention core alone (projections are outside): a trace
+    # reduction reads its device time by this scope, forward and backward
+    with jax.named_scope("attention"):
+        if attn_fn is not None:
+            attn, extra = attn_fn(q, k, v, window)
+        elif cfg.attn_impl == "pallas":
+            # TPU hot-spot path: fused flash kernel with dynamic block
+            # skipping (validated against chunked_attention in
+            # tests/test_kernels.py)
+            from repro.kernels import flash_attention
+            attn = flash_attention(
+                q, k, v, causal=True, window=window, softcap=cfg.attn_softcap,
+            )
+        else:
+            attn = chunked_attention(
+                q, k, v,
+                causal=True,
+                window=window,
+                softcap=cfg.attn_softcap,
+                q_chunk=min(256, q.shape[1]),
+                kv_chunk=min(256, k.shape[1]),
+            )
     B, S = x.shape[:2]
     cdt = jnp.dtype(cfg.compute_dtype)
     o = jnp.einsum(

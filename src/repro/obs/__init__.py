@@ -14,12 +14,13 @@ from .metrics import (Counter, Gauge, Histogram, MetricMap, MetricsRegistry,
                       merged_histogram)
 from .recorder import FlightRecorder
 from .trace import (NULL_SPAN, Tracer, load_jsonl, request_timeline,
-                    to_chrome_trace, to_jsonl, validate)
+                    request_track, to_chrome_trace, to_jsonl, validate)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricMap", "MetricsRegistry",
     "merged_histogram", "FlightRecorder", "Tracer", "NULL_SPAN",
-    "load_jsonl", "request_timeline", "to_chrome_trace", "to_jsonl",
+    "load_jsonl", "request_timeline", "request_track", "to_chrome_trace",
+    "to_jsonl",
     "validate", "Observer",
 ]
 
@@ -79,6 +80,19 @@ class Observer:
     def end(self, sid, **kw):
         if self.tracer is not None and sid is not None:
             self.tracer.end(sid, **kw)
+
+    def open_request(self, rid):
+        """Begin request ``rid``'s ``queue_wait`` on its own track: it
+        entered a queue and waits for a lane."""
+        if self.tracer is not None:
+            self.tracer.begin("queue_wait", cat="request",
+                              track=request_track(rid), rid=rid)
+
+    def close_request(self, rid, **kw):
+        """End whatever request ``rid``'s own track still holds open (its
+        ``queue_wait``, at its first admission or its terminal)."""
+        if self.tracer is not None:
+            self.tracer.end_track(request_track(rid), **kw)
 
     def record(self, kind: str, **fields):
         if self.recorder is not None:

@@ -18,7 +18,16 @@ Event schema (all events):
   ``track`` logical thread ("engine", "replica0", "train", ...)
   ``rid``   request id (request-lifecycle events only, else absent)
   ``sid``   span id (B/E pairs share one; instants have none)
+  ``parent`` sid of the innermost span open on the same track when a span
+            began (B events only; absent for a span with none open)
   ``args``  free-form JSON-able payload
+
+While a tracer exists, every span it opens outside ``cat="request"``
+is mirrored into the JAX profiler as a ``TraceAnnotation`` named
+``repro.<name>`` (a ``StepTraceAnnotation`` when it carries ``step_num``),
+so a profiler trace shows the program's phases on the device ops' clock.
+Request spans (``queue_wait``) are long and overlap, so they stay out of
+the profiler.  The tracer's own events keep their injectable clock.
 
 Request lifecycle phases (``cat == "request"``, ``ph == "i"``) follow
 the taxonomy in docs/observability.md: submit → queue/route → admit →
@@ -35,6 +44,24 @@ import time
 
 TERMINAL = "terminal"
 SUBMIT = "submit"
+PROFILER_PREFIX = "repro."
+
+
+def request_track(rid) -> str:
+    """The track of one request's own spans (its ``queue_wait``)."""
+    return f"request{rid}"
+
+
+def _profiler_annotation(name: str, step_num):
+    """An entered JAX profiler annotation for the span ``name``.  JAX is
+    imported here, not at module level: only an armed tracer needs it."""
+    from jax import profiler
+
+    label = PROFILER_PREFIX + name
+    ann = profiler.TraceAnnotation(label) if step_num is None \
+        else profiler.StepTraceAnnotation(label, step_num=step_num)
+    ann.__enter__()
+    return ann
 
 
 class _NullSpan:
@@ -76,6 +103,8 @@ class Tracer:
         self._sink = sink
         self._next_sid = 0
         self._open: dict[int, dict] = {}
+        self._stacks: dict[str, list[int]] = {}     # open sids per track
+        self._mirrors: dict[int, object] = {}       # sid -> profiler annotation
 
     def _emit(self, ev: dict) -> dict:
         self.events.append(ev)
@@ -84,21 +113,39 @@ class Tracer:
         return ev
 
     def begin(self, name: str, *, cat: str = "engine", track: str = "engine",
-              rid=None, **args) -> int:
+              rid=None, step_num: int | None = None, **args) -> int:
+        """Open a span; returns its sid.  ``step_num`` marks a step of a
+        loop (kept in ``args``; the profiler mirror becomes a
+        ``StepTraceAnnotation``)."""
         sid = self._next_sid
         self._next_sid += 1
+        stack = self._stacks.setdefault(track, [])
         ev = {"ph": "B", "name": name, "cat": cat, "ts": self.clock(),
               "track": track, "sid": sid}
+        if stack:
+            ev["parent"] = stack[-1]
         if rid is not None:
             ev["rid"] = rid
+        if step_num is not None:
+            args["step_num"] = step_num
         if args:
             ev["args"] = args
+        stack.append(sid)
         self._open[sid] = ev
+        if cat != "request":
+            self._mirrors[sid] = _profiler_annotation(name, step_num)
         self._emit(ev)
         return sid
 
     def end(self, sid: int, **args) -> None:
+        ann = self._mirrors.pop(sid, None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         opened = self._open.pop(sid)
+        stack = self._stacks[opened["track"]]
+        stack.remove(sid)
+        if not stack:
+            del self._stacks[opened["track"]]
         ev = {"ph": "E", "name": opened["name"], "cat": opened["cat"],
               "ts": self.clock(), "track": opened["track"], "sid": sid}
         if "rid" in opened:
@@ -109,6 +156,12 @@ class Tracer:
 
     def span(self, name: str, **kw) -> _Span:
         return _Span(self, self.begin(name, **kw))
+
+    def end_track(self, track: str, **args) -> None:
+        """End every span still open on ``track``, innermost first."""
+        stack = self._stacks.get(track)
+        while stack:
+            self.end(stack[-1], **args)
 
     def instant(self, name: str, *, cat: str = "engine", track: str = "engine",
                 rid=None, **args) -> dict:
@@ -166,6 +219,8 @@ def to_chrome_trace(events, path: str | None = None) -> dict:
         args = dict(ev.get("args", ()))
         if "rid" in ev:
             args["rid"] = ev["rid"]
+        if "parent" in ev:
+            args["parent"] = ev["parent"]
         if args:
             row["args"] = args
         out.append(row)
@@ -198,6 +253,8 @@ def validate(events) -> dict:
       - timestamps are globally non-decreasing
       - B/E spans balance LIFO per track, with non-negative duration,
         and no span is left open
+      - a span's ``parent``, where given, is open on its track when it
+        begins
       - per request id: first lifecycle event is 'submit'; at most one
         'terminal' and nothing follows it
     """
@@ -217,6 +274,11 @@ def validate(events) -> dict:
         last_ts = ev["ts"]
         stack = open_stacks.setdefault(ev["track"], [])
         if ev["ph"] == "B":
+            if "parent" in ev:
+                assert any(o.get("sid") == ev["parent"] for o in stack), \
+                    f"event {i}: span {ev['name']!r} names parent " \
+                    f"{ev['parent']} that is not open on track " \
+                    f"{ev['track']!r}"
             stack.append(ev)
         elif ev["ph"] == "E":
             assert stack, f"event {i}: E {ev['name']!r} with no open span " \

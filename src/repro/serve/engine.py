@@ -898,6 +898,7 @@ class ServeEngine:
         if self.obs.tracer is not None:
             self.obs.mark("submit", rid, track=self._track,
                           plen=int(prompt.size), max_new=max_new_tokens)
+            self.obs.open_request(rid)
         return rid
 
     def cancel(self, rid: int) -> bool:
@@ -1547,6 +1548,7 @@ class ServeEngine:
         plen = int(req.prompt.size)
         limit = req.limit if req.resume else plen + req.max_new_tokens - 1
         if self.obs.tracer is not None:
+            self.obs.close_request(req.rid, admitted=True)
             self.obs.mark("admit", req.rid, track=self._track, slot=slot,
                           resume=req.resume)
         if not req.resume:
@@ -1774,6 +1776,7 @@ class ServeEngine:
                 max(0.0, (comp.finish_time - comp.submit_time) * 1e3
                     / len(comp.tokens)))
         if self.obs.tracer is not None:
+            self.obs.close_request(comp.rid, admitted=False)
             self.obs.mark("terminal", comp.rid, track=self._track,
                           status=st, tokens=len(comp.tokens),
                           retries=comp.retries)
@@ -1980,8 +1983,22 @@ class ServeEngine:
         """Advance in-flight chunked prefills (one chunk per lane), admit
         every queued request a free slot (and, paged, the block budget)
         can take, then advance all fully-prefilled lanes by one token.
-        Returns False when idle."""
+        Returns False when idle.
+
+        Traced, the step is the span ``engine.step`` (numbered by the
+        decode steps before it), holding ``engine.admit`` (the admission
+        loop), ``decode`` (``decode.dispatch`` then ``decode.fetch``, the
+        one host sync) and ``engine.advance`` (the per-lane bookkeeping
+        after the fetch, to the end of the step)."""
+        if self.obs.tracer is None:
+            return self._step()
+        with self.obs.span("engine.step", track=self._track,
+                           step_num=self.counters["decode_steps"]):
+            return self._step()
+
+    def _step(self) -> bool:
         progressed = False
+        advance_sid = None
         if self._has_deadlines:
             self._expire_deadlines()
         if self.tier is not None and self.econ.park_idle_s is not None:
@@ -1997,6 +2014,8 @@ class ServeEngine:
                 self._prefill_next_chunk(slot)
                 progressed = True
 
+        admit_sid = None if self.obs.tracer is None else self.obs.begin(
+            "engine.admit", track=self._track)
         for slot in self.free_slots():
             if self.slots[slot] is not None:    # refilled by a resume
                 continue
@@ -2012,6 +2031,8 @@ class ServeEngine:
                 break
             self._admit(self.queue.popleft(), slot)
             progressed = True
+        if admit_sid is not None:
+            self.obs.end(admit_sid)
 
         def active():
             return [
@@ -2053,8 +2074,12 @@ class ServeEngine:
             # the decode span covers dispatch AND the token fetch — the
             # one per-step host sync — so its duration is the real
             # step-critical path, measured by the engine's own clock
-            sid = None if self.obs.tracer is None else self.obs.begin(
-                "decode", track=self._track, lanes=len(active_slots))
+            sid = dispatch_sid = None
+            if self.obs.tracer is not None:
+                sid = self.obs.begin("decode", track=self._track,
+                                     lanes=len(active_slots))
+                dispatch_sid = self.obs.begin("decode.dispatch",
+                                              track=self._track)
             if self.spec:
                 self.state, out = self._spec_exe()(
                     self.params, self.draft_params, self.state)
@@ -2068,18 +2093,25 @@ class ServeEngine:
             self.counters["decode_steps"] += 1
             self.counters["dead_slot_steps"] += (
                 self.econ.max_slots - len(active_slots))
+            if dispatch_sid is not None:
+                self.obs.end(dispatch_sid)
+                fetch_sid = self.obs.begin("decode.fetch", track=self._track)
+            # the one per-step host sync: (max_slots, k+1) verify rows,
+            # sampled tokens, or logits for host sampling
+            fetched = np.asarray(out)
+            if dispatch_sid is not None:
+                self.obs.end(fetch_sid)
             if self.spec:
-                # (max_slots, k+1) verify rows — still ONE int32 fetch
-                rows = np.asarray(out)
+                rows = fetched
                 self.counters["spec_steps"] += 1
                 self.counters["spec_rounds"] += len(active_slots)
             elif self.econ.fused_sampling:
-                toks = np.asarray(out)          # the one per-step host sync
+                toks = fetched
             else:
                 arr = lambda f, d, dt: np.array([
                     f(s) if s is not None else d for s in self.slots
                 ], dtype=dt)
-                logits = np.asarray(out)
+                logits = fetched
                 toks = self._host_sample(
                     logits, sub,
                     arr(lambda s: s.temperature, 0.0, np.float32),
@@ -2088,7 +2120,10 @@ class ServeEngine:
                 toks = np.where(
                     np.isfinite(logits).all(axis=-1), toks,
                     np.int32(NONFINITE_TOKEN))  # host twin of the sentinel
-            self.obs.end(sid)
+            if sid is not None:
+                self.obs.end(sid)
+                advance_sid = self.obs.begin("engine.advance",
+                                             track=self._track)
             if self.faults is not None:
                 lane = self.faults.pick("decode_logits", active_slots)
                 if lane is not None:
@@ -2144,6 +2179,8 @@ class ServeEngine:
                 self._writeback_sampled()
             progressed = True
         self._note_kv_usage()
+        if advance_sid is not None:
+            self.obs.end(advance_sid)
         return progressed
 
     def drain(self) -> None:

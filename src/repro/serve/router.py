@@ -267,6 +267,8 @@ class Router:
             return rid
         self.records[rid] = rec
         self.queue.append(rec)
+        if self.obs.tracer is not None:
+            self.obs.open_request(rid)
         return rid
 
     def cancel(self, rid: int) -> bool:
@@ -299,7 +301,14 @@ class Router:
         """One router tick: inject replica faults, expire router-queue
         deadlines, dispatch, step the replicas, sync stream mirrors,
         collect completions, health-check.  Returns True iff anything
-        progressed (fault detection counts: it unblocks work)."""
+        progressed (fault detection counts: it unblocks work).  Traced,
+        the tick is the span ``router.step``."""
+        if self.obs.tracer is None:
+            return self._tick()
+        with self.obs.span("router.step", track=self._track):
+            return self._tick()
+
+    def _tick(self) -> bool:
         self.tick += 1
         progressed = self._inject_replica_faults()
         progressed |= self._expire_queue_deadlines()
@@ -439,6 +448,7 @@ class Router:
                 max(0.0, (comp.finish_time - comp.submit_time) * 1e3
                     / len(comp.tokens)))
         if self.obs.tracer is not None:
+            self.obs.close_request(rec.rid, admitted=False)
             self.obs.mark("terminal", rec.rid, track=self._track,
                           status=status, tokens=len(comp.tokens),
                           error=error)

@@ -264,7 +264,10 @@ def _build_flat_train_step(cfg, mesh, rules, opt, settings, mode: str):
             loss, metrics, grads = compute_grads(params, batch)
         loss = jax.lax.pmean(loss, daxes)
         metrics = jax.tree.map(lambda x: jax.lax.pmean(x, daxes), metrics)
-        gflat = flatten(layout, grads)
+        # named scopes tag the engine's parts in the compiled module's
+        # metadata (op_name), where a trace reduction finds them by name
+        with jax.named_scope("flat_engine.flatten"):
+            gflat = flatten(layout, grads)
         step = opt_state["step"] + 1
         adam_kw = dict(
             lr=opt.lr, beta1=opt.beta1, beta2=opt.beta2, eps=opt.eps,
@@ -274,69 +277,80 @@ def _build_flat_train_step(cfg, mesh, rules, opt, settings, mode: str):
         if mode == "faithful":
             # Appendix A, bucketed: every worker ends with the full mean
             # gradient; update replicated flat p/m/v buffers in one pass.
-            gflat = bucketed_all_reduce(gflat, buckets, daxes, op="mean")
-            # skip-step verdict AFTER the all-reduce: one worker's NaN
-            # poisons every worker's mean, so the check is globally
-            # consistent with no extra collective
-            ok = jnp.all(jnp.isfinite(gflat)) \
-                if settings.skip_nonfinite else None
-            if opt.grad_clip:
-                gflat, gnorm = _clip(jnp.sum(jnp.square(gflat)), gflat)
-                metrics = {**metrics, "grad_norm": gnorm}
-            pflat = flatten(layout, params)
-            mflat = flatten(layout, opt_state["m"])
-            vflat = flatten(layout, opt_state["v"])
-            p2, m2, v2 = flat_adam_apply(
-                pflat, gflat, mflat, vflat, step, **adam_kw
-            )
-            if ok is not None:
-                # bitwise no-op on skip: keep the pre-update buffers and
-                # don't advance the Adam step counter (bias correction
-                # must not decay across a skipped step)
-                p2 = jnp.where(ok, p2, pflat)
-                m2 = jnp.where(ok, m2, mflat)
-                v2 = jnp.where(ok, v2, vflat)
-                step = opt_state["step"] + ok.astype(step.dtype)
-                metrics = {**metrics,
-                           "skipped": 1.0 - ok.astype(jnp.float32)}
-            new_params = unflatten(layout, p2)
-            new_state = {
-                "step": step,
-                "m": unflatten(layout, m2, dtype=jnp.float32),
-                "v": unflatten(layout, v2, dtype=jnp.float32),
-            }
+            with jax.named_scope("flat_engine.allreduce"):
+                gflat = bucketed_all_reduce(gflat, buckets, daxes, op="mean")
+            with jax.named_scope("flat_engine.flatten"):
+                pflat = flatten(layout, params)
+                mflat = flatten(layout, opt_state["m"])
+                vflat = flatten(layout, opt_state["v"])
+            with jax.named_scope("flat_engine.adam"):
+                # skip-step verdict AFTER the all-reduce: one worker's NaN
+                # poisons every worker's mean, so the check is globally
+                # consistent with no extra collective
+                ok = jnp.all(jnp.isfinite(gflat)) \
+                    if settings.skip_nonfinite else None
+                if opt.grad_clip:
+                    gflat, gnorm = _clip(jnp.sum(jnp.square(gflat)), gflat)
+                    metrics = {**metrics, "grad_norm": gnorm}
+                p2, m2, v2 = flat_adam_apply(
+                    pflat, gflat, mflat, vflat, step, **adam_kw
+                )
+                if ok is not None:
+                    # bitwise no-op on skip: keep the pre-update buffers
+                    # and don't advance the Adam step counter (bias
+                    # correction must not decay across a skipped step)
+                    p2 = jnp.where(ok, p2, pflat)
+                    m2 = jnp.where(ok, m2, mflat)
+                    v2 = jnp.where(ok, v2, vflat)
+                    step = opt_state["step"] + ok.astype(step.dtype)
+                    metrics = {**metrics,
+                               "skipped": 1.0 - ok.astype(jnp.float32)}
+            with jax.named_scope("flat_engine.unflatten"):
+                new_params = unflatten(layout, p2)
+                new_state = {
+                    "step": step,
+                    "m": unflatten(layout, m2, dtype=jnp.float32),
+                    "v": unflatten(layout, v2, dtype=jnp.float32),
+                }
             return new_params, new_state, {"loss": loss, **metrics}
 
         # ZeRO: own 1/N of every bucket; m/v live scattered (flat, sharded)
-        g_loc = bucketed_reduce_scatter(gflat, buckets, daxes[0], op="mean")
-        # the scatter localizes a NaN to whichever shard owns that region,
-        # so the skip verdict needs a psum'd count to stay in lockstep
-        ok = None
-        if settings.skip_nonfinite:
-            bad = jax.lax.psum(
-                jnp.sum((~jnp.isfinite(g_loc)).astype(jnp.int32)), daxes)
-            ok = bad == 0
-        if opt.grad_clip:
-            g_loc, gnorm = _clip(
-                jax.lax.psum(jnp.sum(jnp.square(g_loc)), daxes), g_loc
+        with jax.named_scope("flat_engine.allreduce"):
+            g_loc = bucketed_reduce_scatter(
+                gflat, buckets, daxes[0], op="mean")
+        with jax.named_scope("flat_engine.flatten"):
+            widx = jax.lax.axis_index(daxes[0])
+            p_loc = scatter_flat(flatten(layout, params), buckets, widx)
+        with jax.named_scope("flat_engine.adam"):
+            # the scatter localizes a NaN to whichever shard owns that
+            # region, so the skip verdict needs a psum'd count to stay in
+            # lockstep
+            ok = None
+            if settings.skip_nonfinite:
+                bad = jax.lax.psum(
+                    jnp.sum((~jnp.isfinite(g_loc)).astype(jnp.int32)), daxes)
+                ok = bad == 0
+            if opt.grad_clip:
+                g_loc, gnorm = _clip(
+                    jax.lax.psum(jnp.sum(jnp.square(g_loc)), daxes), g_loc
+                )
+                metrics = {**metrics, "grad_norm": gnorm}
+            p2, m2, v2 = flat_adam_apply(
+                p_loc, g_loc, opt_state["m"], opt_state["v"], step, **adam_kw
             )
-            metrics = {**metrics, "grad_norm": gnorm}
-        widx = jax.lax.axis_index(daxes[0])
-        p_loc = scatter_flat(flatten(layout, params), buckets, widx)
-        p2, m2, v2 = flat_adam_apply(
-            p_loc, g_loc, opt_state["m"], opt_state["v"], step, **adam_kw
-        )
-        if ok is not None:
-            # params reassemble through all-gather of the (unchanged)
-            # shard — pure data movement, so the round trip is bitwise
-            p2 = jnp.where(ok, p2, p_loc)
-            m2 = jnp.where(ok, m2, opt_state["m"])
-            v2 = jnp.where(ok, v2, opt_state["v"])
-            step = opt_state["step"] + ok.astype(step.dtype)
-            metrics = {**metrics, "skipped": 1.0 - ok.astype(jnp.float32)}
-        new_params = unflatten(
-            layout, bucketed_all_gather(p2, buckets, daxes[0])
-        )
+            if ok is not None:
+                # params reassemble through all-gather of the (unchanged)
+                # shard — pure data movement, so the round trip is bitwise
+                p2 = jnp.where(ok, p2, p_loc)
+                m2 = jnp.where(ok, m2, opt_state["m"])
+                v2 = jnp.where(ok, v2, opt_state["v"])
+                step = opt_state["step"] + ok.astype(step.dtype)
+                metrics = {**metrics,
+                           "skipped": 1.0 - ok.astype(jnp.float32)}
+        with jax.named_scope("flat_engine.allreduce"):
+            p_full = bucketed_all_gather(p2, buckets, daxes[0])
+        with jax.named_scope("flat_engine.unflatten"):
+            new_params = unflatten(layout, p_full)
         new_state = {"step": step, "m": m2, "v": v2}
         return new_params, new_state, {"loss": loss, **metrics}
 

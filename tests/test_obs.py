@@ -15,7 +15,11 @@ Coverage:
 - tracing is behavior-invisible: the same fuzz stream driven with the
   observer fully armed (fake clock shared engine<->tracer) is bitwise
   token-identical to the untraced drive with zero new executable builds;
-- ``AotCache`` per-key build timing and the slowest-builds report.
+- ``AotCache`` per-key build timing and the slowest-builds report;
+- an armed tracer mirrors its spans into the JAX profiler as nested
+  ``repro.<name>`` host annotations (request spans stay out), and records
+  each span's parent; the engine's and router's host phases nest under
+  ``engine.step``, and each admitted request has one ``queue_wait``.
 """
 import dataclasses
 import json
@@ -31,8 +35,8 @@ from repro.models import registry
 from repro.models.common import ShardRules
 from repro.obs import (
     FlightRecorder, MetricMap, MetricsRegistry, Observer, Tracer,
-    load_jsonl, merged_histogram, request_timeline, to_chrome_trace,
-    to_jsonl, validate,
+    load_jsonl, merged_histogram, request_timeline, request_track,
+    to_chrome_trace, to_jsonl, validate,
 )
 from repro.serve import EngineConfig, ServeEngine
 from repro.serve.router import Router, RouterConfig
@@ -215,6 +219,72 @@ def test_validate_rejects_malformed_streams():
         validate(tr3.events)
 
 
+def test_span_parents_recorded_checked_and_exported(tmp_path):
+    tr = Tracer(FakeClock())
+    with tr.span("engine.step", track="e", step_num=4):
+        with tr.span("decode", track="e"):
+            with tr.span("decode.fetch", track="e"):
+                pass
+        with tr.span("other", track="f"):      # another track: no parent
+            pass
+    begins = {e["name"]: e for e in tr.events if e["ph"] == "B"}
+    assert "parent" not in begins["engine.step"]
+    assert begins["decode"]["parent"] == begins["engine.step"]["sid"]
+    assert begins["decode.fetch"]["parent"] == begins["decode"]["sid"]
+    assert "parent" not in begins["other"]
+    assert begins["engine.step"]["args"] == {"step_num": 4}
+    validate(tr.events)
+    rows = to_chrome_trace(tr.events)["traceEvents"]
+    fetch = next(r for r in rows if r["name"] == "decode.fetch"
+                 and r["ph"] == "B")
+    assert fetch["args"]["parent"] == begins["decode"]["sid"]
+
+    bad = [dict(e) for e in tr.events]
+    bad[1]["parent"] = 99                      # names a span never opened
+    with pytest.raises(AssertionError, match="parent"):
+        validate(bad)
+
+
+def test_armed_tracer_mirrors_spans_into_profiler(tmp_path):
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    unarmed = Observer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("router.step", track="router"):
+            with tr.span("engine.step", track="replica0", step_num=7):
+                with tr.span("decode", track="replica0"):
+                    jax.numpy.ones(4).block_until_ready()
+            tr.mark("submit", 3)
+            sid = tr.begin("queue_wait", cat="request",
+                           track=request_track(3), rid=3)
+            tr.end(sid)
+        with unarmed.span("ghost"):
+            pass
+        unarmed.end(unarmed.begin("ghost2"))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.") or "ghost" in e.name]
+    spans = {name: (s, e) for s, e, name in host}
+    assert sorted(spans) == ["repro.decode", "repro.engine.step",
+                             "repro.router.step"]
+    assert len(host) == 3          # no queue_wait, nothing from unarmed
+
+    def inside(child, parent):
+        return spans[parent][0] <= spans[child][0] \
+            and spans[child][1] <= spans[parent][1]
+
+    assert inside("repro.decode", "repro.engine.step")
+    assert inside("repro.engine.step", "repro.router.step")
+    assert tr._mirrors == {}                    # every annotation closed
+
+
 def test_flight_recorder_ring_bounds_and_dump(tmp_path):
     clock = FakeClock()
     rec = FlightRecorder(capacity=8, clock=clock, dump_dir=str(tmp_path))
@@ -384,16 +454,93 @@ def test_traced_drive_is_bitwise_and_builds_flat(setup):
             clock.t += 1.0
             tick += 1
             assert tick < 2000
-        return [list(eng.completions[r].tokens) for r in range(len(stream))]
+        return ([list(eng.completions[r].tokens) for r in range(len(stream))],
+                eng.counters["decode_steps"])
 
     builds0 = aot.stats["builds"]
     want = drive(None)
     obs = Observer.full(clock=FakeClock(), name="engine")
     got = drive(obs)
-    assert got == want, "arming the observer changed greedy tokens"
+    assert got == want, "arming the observer changed greedy tokens or the " \
+        "number of decode steps"
     assert aot.stats["builds"] == builds0, \
         "tracing forced fresh executable builds"
     validate(obs.tracer.events)
+    _check_host_phases(obs.tracer.events)
+
+
+def _check_host_phases(events):
+    """Every ``decode`` and ``prefill_chunk`` has an ``engine.step``
+    among its ancestors; ``decode.dispatch`` and ``decode.fetch`` are the
+    children of a ``decode``; each admitted request has exactly one
+    ``queue_wait``, ended at or before its first admission."""
+    begins = {e["sid"]: e for e in events if e["ph"] == "B"}
+
+    def ancestors(ev):
+        while "parent" in ev:
+            ev = begins[ev["parent"]]
+            yield ev["name"]
+
+    names = [e["name"] for e in begins.values()]
+    for phase in ("engine.step", "engine.admit", "decode", "decode.dispatch",
+                  "decode.fetch", "engine.advance", "prefill_chunk"):
+        assert phase in names, phase
+    for ev in begins.values():
+        if ev["name"] in ("decode", "prefill_chunk", "engine.admit",
+                          "engine.advance"):
+            assert "engine.step" in ancestors(ev), ev
+        if ev["name"] in ("decode.dispatch", "decode.fetch"):
+            assert begins[ev["parent"]]["name"] == "decode", ev
+    waits = [e["rid"] for e in begins.values() if e["name"] == "queue_wait"]
+    admitted = {e["rid"] for e in events if e["name"] == "admit"}
+    assert sorted(waits) == sorted(admitted)
+    for e in events:
+        if e["name"] == "queue_wait" and e["ph"] == "E":
+            assert e["args"] == {"admitted": True}
+
+
+def test_router_traced_drive_host_phases_and_parity(setup):
+    """The router's drive with the observer armed: router.step holds the
+    replica's engine.step (another track), every request waits once, and
+    tokens and decode steps match the untraced drive bitwise."""
+    cfg, mesh, rules, params, aot = setup
+
+    def drive(obs):
+        clock = FakeClock()
+        if obs is not None:
+            obs.tracer.clock = clock
+        router = Router(cfg, mesh, rules, params, SLOTTED,
+                        RouterConfig(replicas=1, shed_queue_depth=10_000),
+                        aot=aot, clock=clock, obs=obs)
+        stream = make_stream(np.random.default_rng(7), cfg.vocab)
+        i, tick = 0, 0
+        while i < len(stream) or router.has_work():
+            while i < len(stream) and stream[i][0] <= tick:
+                _, prompt, budget = stream[i]
+                router.submit(prompt, max_new_tokens=budget, rid=i)
+                i += 1
+            router.step()
+            clock.t += 1.0
+            tick += 1
+            assert tick < 2000
+        steps = router.replicas[0].engine.counters["decode_steps"]
+        return [list(router.completions[r].tokens)
+                for r in range(len(stream))], steps
+
+    want = drive(None)
+    obs = Observer.full(clock=FakeClock(), name="router")
+    got = drive(obs)
+    assert got == want
+    info = validate(obs.tracer.events)
+    assert info["terminals"] == len(want[0])
+    _check_host_phases(obs.tracer.events)
+    ticks = [e for e in obs.tracer.events
+             if e["ph"] == "B" and e["name"] == "router.step"]
+    steps = [e for e in obs.tracer.events
+             if e["ph"] == "B" and e["name"] == "engine.step"]
+    assert ticks and all(e["track"] == "router" for e in ticks)
+    assert len(steps) <= len(ticks)
+    assert {e["track"] for e in steps} == {"replica0"}
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,8 @@ one process may hold the TPU library, so under several test workers only
 the worker running this file loads it.  Kernels the compiler still refuses
 at these widths (paged attention, SSD) are recorded in ROADMAP.md.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -60,6 +62,8 @@ def test_flat_adam_compiles_at_smollm_flat_length(one_chip, n_shards, length):
     # p', m', v' are written in place of the donated p, m, v (the
     # compiler counts padded allocations, so at least their bytes)
     assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * length
+    # the kernel's name reaches its instruction, which a trace names it by
+    assert re.search(r"%flat_adam[.\d]* = .* custom-call\(", compiled.as_text())
 
 
 @pytest.mark.parametrize("fused_add", [False, True])
