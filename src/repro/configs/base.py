@@ -63,7 +63,10 @@ class ArchConfig:
     qk_norm: bool = False            # qwen3 QK-RMSNorm
     query_scale: float = 0.0         # 0 -> head_dim**-0.5 (gemma2 overrides)
     gate_act: str = "silu"           # ffn gate activation ("silu" | "gelu")
-    attn_impl: str = "chunked"       # "chunked" (XLA) | "pallas" (TPU kernel)
+    # "auto": the Pallas flash kernels (forward and backward) where they
+    # apply on a TPU (models/lm._attn_impl), else chunked_attention;
+    # "chunked" (XLA) and "pallas" force a path
+    attn_impl: str = "auto"
 
     moe: MoEConfig = MoEConfig()
     ssm: SSMConfig = SSMConfig()

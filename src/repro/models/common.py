@@ -114,6 +114,11 @@ class manual_mode:
         return False
 
 
+def in_manual_mode() -> bool:
+    """Whether tracing is inside a per-worker ``manual_mode()`` program."""
+    return _MANUAL_MODE
+
+
 def constrain(x, rules: ShardRules, *logical: str | None):
     """``with_sharding_constraint`` by logical axes.
 

@@ -33,6 +33,7 @@ from .common import (
     constrain,
     cross_entropy_loss,
     decode_positions,
+    in_manual_mode,
     init_tree,
     rms_norm,
     softcap,
@@ -162,6 +163,22 @@ def _ffn(cfg, mesh, rules, x, bp):
     return out, {"lb_loss": jnp.float32(0.0), "drop_frac": jnp.float32(0.0)}
 
 
+def _attn_impl(cfg, mesh, q, k) -> str:
+    """The attention path of ``_block_fwd``: ``cfg.attn_impl``, with
+    "auto" resolved from what the call shows.  The Pallas flash kernels
+    take the attention where they apply (``flash_applies``) and the
+    attention runs on one device's data (a one-device mesh, or a
+    per-worker program): a Pallas call has no rule to partition it."""
+    if cfg.attn_impl != "auto":
+        return cfg.attn_impl
+    from repro.kernels.flash_attention.ops import flash_applies
+    per_device = mesh.size == 1 or in_manual_mode()
+    if per_device and flash_applies(q.shape, k.shape, q.dtype, causal=True,
+                                    softcap=cfg.attn_softcap):
+        return "pallas"
+    return "chunked"
+
+
 def _block_fwd(cfg, mesh, rules, x, bp, positions, *, window: int,
                collect_kv: bool, attn_fn=None):
     """One transformer block, training/prefill path.
@@ -182,10 +199,9 @@ def _block_fwd(cfg, mesh, rules, x, bp, positions, *, window: int,
     with jax.named_scope("attention"):
         if attn_fn is not None:
             attn, extra = attn_fn(q, k, v, window)
-        elif cfg.attn_impl == "pallas":
-            # TPU hot-spot path: fused flash kernel with dynamic block
-            # skipping (validated against chunked_attention in
-            # tests/test_kernels.py)
+        elif _attn_impl(cfg, mesh, q, k) == "pallas":
+            # the flash kernels, forward and backward, skipping masked
+            # blocks (checked against the reference in tests/test_kernels.py)
             from repro.kernels import flash_attention
             attn = flash_attention(
                 q, k, v, causal=True, window=window, softcap=cfg.attn_softcap,

@@ -14,6 +14,7 @@ from repro.kernels.rmsnorm.ops import rmsnorm_add_op, rmsnorm_op
 from repro.kernels.rmsnorm.ref import rmsnorm_add_ref, rmsnorm_ref
 from repro.kernels.ssd.ops import ssd_op
 from repro.kernels.ssd.ref import ssd_ref
+from repro.models.attention import reference_attention
 from repro.optim.flat import flat_adam_update
 
 
@@ -46,6 +47,41 @@ def test_flash_attention_sweep(dtype, S, H, Hk, D, bq, bk, kwargs):
     ).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("S,bq,bk", [(64, 32, 32), (96, 32, 32), (128, 32, 64)])
+@pytest.mark.parametrize("H,Hk,D", [
+    (2, 2, 32),         # GQA rep 1; the softmax scale multiplies the scores
+    (6, 2, 16),         # rep 3; the scale (1/4) is folded into the tiles
+])
+@pytest.mark.parametrize("window", [0, 40])
+def test_flash_attention_grads(dtype, S, bq, bk, H, Hk, D, window):
+    """dq, dk, dv of the kernels' backward against jax.grad of the fp32
+    reference, on the same inputs, through a random cotangent."""
+    rng = np.random.default_rng(S + H + window)
+    q = jnp.asarray(rng.normal(size=(2, S, H, D)), dtype)
+    k = jnp.asarray(rng.normal(size=(2, S, Hk, D)), dtype)
+    v = jnp.asarray(rng.normal(size=(2, S, Hk, D)), dtype)
+    w = jnp.asarray(rng.normal(size=(2, S, H, D)), jnp.float32)
+
+    def kernel_loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              block_q=bq, block_k=bk)
+        return jnp.sum(out.astype(jnp.float32) * w)
+
+    def ref_loss(q, k, v):
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        return jnp.sum(reference_attention(*f32, causal=True, window=window) * w)
+
+    got = jax.grad(kernel_loss, (0, 1, 2))(q, k, v)
+    want = jax.grad(ref_loss, (0, 1, 2))(q, k, v)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for name, g, r in zip("qkv", got, want):
+        assert g.dtype == dtype, name
+        r = np.asarray(r)
+        np.testing.assert_allclose(np.asarray(g, np.float32), r, rtol=tol,
+                                   atol=tol * np.abs(r).max(), err_msg=f"d{name}")
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flat_adam.kernel import flat_adam
 from repro.kernels.rmsnorm.kernel import rmsnorm, rmsnorm_add
 from repro.optim.buckets import make_buckets
@@ -86,3 +87,25 @@ def test_flash_attention_compiles_at_smollm_heads(one_chip):
                               sharding=one_chip)
     _compile(lambda q, k, v: flash_attention_fwd(q, k, v, interpret=False),
              q, kv, kv)
+
+
+def test_flash_attention_grad_compiles_at_smollm_train_shape(one_chip):
+    """The train cell's attention core: forward and jax.grad through the
+    kernels at smollm-360m heads, B=8, S=2048, bf16."""
+    cfg = get_config("smollm-360m")
+    B, S, D = 8, 2048, cfg.head_dim
+    q = jax.ShapeDtypeStruct((B, S, cfg.n_heads, D), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, cfg.n_kv, D), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    fwd = _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False),
+                   q, kv, kv)
+    assert re.search(r"%flash_attention_fwd[.\d]* = ", fwd.as_text())
+    grad = _compile(jax.grad(loss, (0, 1, 2)), q, kv, kv).as_text()
+    names = set(re.findall(r"%(flash_attention_\w+?)(?:\.\d+)? = ", grad))
+    assert names == {"flash_attention_fwd", "flash_attention_bwd"}, names
