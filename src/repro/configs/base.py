@@ -17,6 +17,7 @@ ARCH_IDS = (
     "gemma2-27b",
     "internvl2-76b",
     "zamba2-1.2b",
+    "zamba2-7b",
     "whisper-tiny",
     "xlstm-1.3b",
     "qwen3-moe-30b-a3b",
@@ -61,8 +62,9 @@ class ArchConfig:
     logit_softcap: float = 0.0       # gemma2 final-logit softcap
     rope_theta: float = 10_000.0
     qk_norm: bool = False            # qwen3 QK-RMSNorm
-    query_scale: float = 0.0         # 0 -> head_dim**-0.5 (gemma2 overrides)
-    gate_act: str = "silu"           # ffn gate activation ("silu" | "gelu")
+    query_scale: float = 0.0         # 0 -> head_dim**-0.5 (gemma2, zamba2 override)
+    # ffn gate activation, by its published name (models/common.ACTIVATIONS)
+    gate_act: str = "silu"
     # "auto": the Pallas flash kernels (forward and backward) where they
     # apply on a TPU (models/lm._attn_impl), else chunked_attention;
     # "chunked" (XLA) and "pallas" force a path
@@ -71,8 +73,15 @@ class ArchConfig:
     moe: MoEConfig = MoEConfig()
     ssm: SSMConfig = SSMConfig()
 
-    # hybrid / xlstm block layout
-    attn_every: int = 0        # zamba2: shared attn block every k SSM layers
+    # zamba2 (models/zamba.py), by the published keys: the layers that run
+    # a shared attention+MLP block before their Mamba2 mixer, how many
+    # shared blocks alternate over them, and each such layer's own LoRA
+    # rank on the shared MLP (and on q/k/v with the attention adapter)
+    hybrid_layer_ids: tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
+    use_shared_attention_adapter: bool = False
+    # xlstm block layout
     slstm_every: int = 0       # xlstm: one sLSTM per k blocks (rest mLSTM)
 
     # encoder-decoder (whisper)

@@ -21,7 +21,7 @@ CONFIG = ArchConfig(
     attn_softcap=50.0,
     logit_softcap=30.0,
     query_scale=(4608 / 32) ** -0.5,   # query_pre_attn_scalar = d_model/n_heads
-    gate_act="gelu",                   # GeGLU
+    gate_act="gelu_pytorch_tanh",      # GeGLU, tanh form
     tie_embeddings=True,
 )
 

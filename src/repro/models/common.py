@@ -17,6 +17,7 @@ dimension names to mesh axes:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Sequence
 
 import jax
@@ -250,6 +251,15 @@ def rms_norm(x, gamma, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     y = x * jax.lax.rsqrt(var + eps)
     return (y * (1.0 + gamma.astype(jnp.float32))).astype(dt)
+
+
+# activations by the names published configs give them (transformers'
+# ACT2FN): "gelu" is the exact erf form, "gelu_pytorch_tanh" the tanh one
+ACTIVATIONS = {
+    "silu": jax.nn.silu,
+    "gelu": functools.partial(jax.nn.gelu, approximate=False),
+    "gelu_pytorch_tanh": functools.partial(jax.nn.gelu, approximate=True),
+}
 
 
 def softcap(x, cap: float):

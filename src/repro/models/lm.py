@@ -28,6 +28,7 @@ from .attention import (
     rope,
 )
 from .common import (
+    ACTIVATIONS,
     ParamSpec,
     ShardRules,
     constrain,
@@ -114,10 +115,10 @@ def init(cfg: ArchConfig, key) -> dict:
 
 
 def _gate(cfg: ArchConfig, g):
-    return jax.nn.gelu(g) if cfg.gate_act == "gelu" else jax.nn.silu(g)
+    return ACTIVATIONS[cfg.gate_act](g)
 
 
-def _q_scale(cfg: ArchConfig) -> float:
+def q_scale(cfg: ArchConfig) -> float:
     # chunked_attention applies dh**-0.5; fold any override into q.
     if cfg.query_scale:
         return cfg.query_scale * (cfg.head_dim ** 0.5)
@@ -138,7 +139,7 @@ def _attn_proj(cfg, mesh, rules, h, bp, positions):
     if cfg.qk_norm:
         q = rms_norm(q, bp["qnorm"], cfg.norm_eps)
         k = rms_norm(k, bp["knorm"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta) * _q_scale(cfg)
+    q = rope(q, positions, cfg.rope_theta) * q_scale(cfg)
     k = rope(k, positions, cfg.rope_theta)
     tp = _tp_size(mesh, rules)
     q = constrain(q, rules, "dp", None, "tp" if H % tp == 0 else None, None)
@@ -256,7 +257,7 @@ def _block_decode(cfg, mesh, rules, x, bp, kc, vc, cur_index, *, window: int,
         q = rms_norm(q, bp["qnorm"], cfg.norm_eps)
         k = rms_norm(k, bp["knorm"], cfg.norm_eps)
     pos = decode_positions(cur_index, B)
-    q = rope(q[:, None], pos, cfg.rope_theta)[:, 0] * _q_scale(cfg)
+    q = rope(q[:, None], pos, cfg.rope_theta)[:, 0] * q_scale(cfg)
     k = rope(k[:, None], pos, cfg.rope_theta)[:, 0]
     q = q.reshape(B, Hk, H // Hk, dh)
     if attn_fn is None:

@@ -207,9 +207,21 @@ def _causal_conv(x, w, b, state=None, state_len=None):
     return y, new_state
 
 
+def group_rms_norm(x, gamma, groups: int, eps: float):
+    """Mamba2's gated-output norm: RMSNorm over each of ``groups`` equal
+    slices of the last axis (one slice is plain ``rms_norm``), gain
+    ``1 + gamma`` over the whole axis."""
+    dt = x.dtype
+    xg = x.astype(jnp.float32).reshape(*x.shape[:-1], groups, -1)
+    xg = xg * jax.lax.rsqrt(jnp.mean(jnp.square(xg), axis=-1, keepdims=True) + eps)
+    return (xg.reshape(x.shape) * (1.0 + gamma.astype(jnp.float32))).astype(dt)
+
+
 def mamba_block_fwd(cfg: ArchConfig, rules: ShardRules, x, bp, *,
-                    return_state: bool = False, valid=None, state_len=None):
-    """x: (B,T,D).  Returns x + mamba(x) (and (ssm, conv) final states).
+                    return_state: bool = False, valid=None, state_len=None,
+                    add=None):
+    """x: (B,T,D).  Returns x + mamba(RMSNorm(x + add)) (and (ssm, conv)
+    final states); ``add`` is a zamba2 hybrid layer's shared-block output.
 
     ``valid`` ((B,T) bool) marks real positions of a right-padded prompt
     bucket (slotted serve prefill): padded steps get ``dt = 0``, which is
@@ -221,7 +233,7 @@ def mamba_block_fwd(cfg: ArchConfig, rules: ShardRules, x, bp, *,
     s = cfg.ssm
     d_inner, H, _ = mamba_dims(cfg)
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = rms_norm(x, bp["ln"], cfg.norm_eps)
+    h = rms_norm(x if add is None else x + add, bp["ln"], cfg.norm_eps)
     proj = jnp.einsum("btd,dk->btk", h, bp["in_proj"].astype(cdt))
     z, xs, bmat, cmat, dt = _split_proj(cfg, proj)
     conv_in = jnp.concatenate([xs, bmat, cmat], axis=-1)
@@ -240,10 +252,12 @@ def mamba_block_fwd(cfg: ArchConfig, rules: ShardRules, x, bp, *,
     if valid is not None:
         dtv = jnp.where(valid[..., None], dtv, 0.0)
     A = -jnp.exp(bp["A_log"].astype(jnp.float32))
-    y, ssm_state = ssd_chunked(xh, dtv, A, bm, cm, chunk=s.chunk, return_state=True)
+    with jax.named_scope("zamba.ssd"):
+        y, ssm_state = ssd_chunked(xh, dtv, A, bm, cm, chunk=s.chunk,
+                                   return_state=True)
     y = y + bp["D_skip"].astype(jnp.float32)[None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B_, T, d_inner).astype(cdt)
-    y = rms_norm(y * jax.nn.silu(z), bp["out_ln"], cfg.norm_eps)
+    y = group_rms_norm(y * jax.nn.silu(z), bp["out_ln"], s.n_groups, cfg.norm_eps)
     out = jnp.einsum("btk,kd->btd", y, bp["out_proj"].astype(cdt))
     out = constrain(x + out, rules, "dp", "sp", None)
     if return_state:
@@ -262,12 +276,14 @@ def mamba_state_specs(cfg: ArchConfig, n_layers: int, batch: int):
     }
 
 
-def mamba_block_decode(cfg: ArchConfig, rules: ShardRules, x, bp, ssm_state, conv_state):
-    """x: (B,D) one token.  Returns (x', ssm_state', conv_state')."""
+def mamba_block_decode(cfg: ArchConfig, rules: ShardRules, x, bp, ssm_state,
+                       conv_state, add=None):
+    """x: (B,D) one token.  Returns (x', ssm_state', conv_state'); ``add``
+    as in :func:`mamba_block_fwd`."""
     s = cfg.ssm
     d_inner, H, _ = mamba_dims(cfg)
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = rms_norm(x, bp["ln"], cfg.norm_eps)
+    h = rms_norm(x if add is None else x + add, bp["ln"], cfg.norm_eps)
     proj = jnp.einsum("bd,dk->bk", h, bp["in_proj"].astype(cdt))
     z, xs, bmat, cmat, dt = _split_proj(cfg, proj)
     conv_in = jnp.concatenate([xs, bmat, cmat], axis=-1)[:, None]
@@ -282,9 +298,10 @@ def mamba_block_decode(cfg: ArchConfig, rules: ShardRules, x, bp, ssm_state, con
     cm = cmat.reshape(B_, s.n_groups, s.state)
     dtv = jax.nn.softplus(dt.astype(jnp.float32) + bp["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(bp["A_log"].astype(jnp.float32))
-    ssm_state, y = ssd_decode_step(ssm_state, xh, dtv, A, bm, cm)
+    with jax.named_scope("zamba.ssd"):
+        ssm_state, y = ssd_decode_step(ssm_state, xh, dtv, A, bm, cm)
     y = y + bp["D_skip"].astype(jnp.float32)[None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B_, d_inner).astype(cdt)
-    y = rms_norm(y * jax.nn.silu(z), bp["out_ln"], cfg.norm_eps)
+    y = group_rms_norm(y * jax.nn.silu(z), bp["out_ln"], s.n_groups, cfg.norm_eps)
     out = jnp.einsum("bk,kd->bd", y, bp["out_proj"].astype(cdt))
     return x + out, ssm_state, conv_state

@@ -1,66 +1,88 @@
-"""Zamba2 hybrid assembly [arXiv:2411.15242]: a stack of Mamba2 layers with
-a single *shared* transformer block (attention + MLP) applied every
-``attn_every`` layers, taking concat(hidden, original embedding) as input
-(Zamba's global skip), projected back to d_model.
+"""Zamba2 hybrid assembly [arXiv:2411.15242; transformers' Zamba2]: a
+stack of Mamba2 layers, some of which (``hybrid_layer_ids``) first run a
+shared transformer block (attention + MLP).
 
-Simplifications vs the released checkpoints (noted in DESIGN.md): the
-per-invocation LoRA deltas on the shared block are omitted; the shared
-block's attention operates at d_model (after the concat projection) rather
-than 2*d_model.
+With x0 the token embedding and x the residual stream, layer i runs
+
+    mamba:   x <- x + Mamba2(RMSNorm(x))
+    hybrid, the k-th of ``hybrid_layer_ids``:
+        u = RMSNorm(concat(x, x0))                              2 x d_model
+        a = o_proj(Attn(RoPE(q_proj u), RoPE(k_proj u), v_proj u))
+        [g, up] = gate_up(h) + B_k A_k h,  h = RMSNorm(a)
+        t = down(act(g) * up)
+        x <- x + Mamba2(RMSNorm(x + linear_k(t)))
+
+The shared weights are those of block ``k % num_mem_blocks``; the rank
+``adapter_rank`` LoRA (A_k, B_k) and ``linear_k`` belong to the k-th
+hybrid layer, as do LoRA deltas on q/k/v where
+``use_shared_attention_adapter`` is set.  Attention is causal over
+``n_heads`` heads of ``head_dim`` (n_heads x head_dim = 2 x d_model),
+scaled by ``query_scale``, RoPE over the whole head.  The shared block has
+no residual of its own: its output reaches the stream only through the
+layer's Mamba2 input.  A final RMSNorm, then logits against the tied
+embedding.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ArchConfig
 from .attention import DecodeSharding, chunked_attention, decode_attention, rope
 from .common import (
-    ParamSpec, ShardRules, constrain, cross_entropy_loss, decode_positions,
-    init_tree, rms_norm,
+    ACTIVATIONS, ParamSpec, ShardRules, constrain, constrain_spec,
+    cross_entropy_loss, decode_positions, init_tree, remat_wrap, rms_norm,
 )
+from .lm import q_scale
 from .ssm import (
-    mamba_block_decode, mamba_block_fwd, mamba_block_specs, mamba_dims,
-    mamba_state_specs,
+    mamba_block_decode, mamba_block_fwd, mamba_block_specs, mamba_state_specs,
 )
 
 
-def _segments(cfg: ArchConfig) -> list[int]:
-    """Layer counts between shared-block invocations."""
-    k = cfg.attn_every
-    segs, rem = [], cfg.n_layers
-    while rem > 0:
-        segs.append(min(k, rem))
-        rem -= k
-    return segs
-
-
-def n_shared_invocations(cfg: ArchConfig) -> int:
-    return len(_segments(cfg))
+def runs(cfg: ArchConfig) -> list[tuple[int, int, int | None]]:
+    """The layers as runs ``(first, end, k)``: a run starts at the k-th
+    hybrid layer (``k`` None for the plain Mamba2 layers before the
+    first) and holds the Mamba2 layers up to the next."""
+    ids = sorted(cfg.hybrid_layer_ids)
+    starts = ([0] if not ids or ids[0] > 0 else []) + ids
+    out = []
+    for j, a in enumerate(starts):
+        b = starts[j + 1] if j + 1 < len(starts) else cfg.n_layers
+        out.append((a, b, ids.index(a) if a in ids else None))
+    return out
 
 
 def param_specs(cfg: ArchConfig) -> dict:
-    D, dh, H, Hk = cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.n_kv
+    D, F, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    A = 2 * D
+    q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv * cfg.head_dim
+    nb, nh = (cfg.num_mem_blocks,), (len(cfg.hybrid_layer_ids),)
     dt = jnp.dtype(cfg.param_dtype)
     shared = {
-        "proj_in": ParamSpec((2 * D, D), ("fsdp", None), dt),
-        "ln1": ParamSpec((D,), (None,), dt, init_scale=0.0),
-        "wq": ParamSpec((D, H * dh), ("fsdp", "tp"), dt),
-        "wk": ParamSpec((D, Hk * dh), ("fsdp", "tp"), dt),
-        "wv": ParamSpec((D, Hk * dh), ("fsdp", "tp"), dt),
-        "wo": ParamSpec((H * dh, D), ("tp", "fsdp"), dt),
-        "ln2": ParamSpec((D,), (None,), dt, init_scale=0.0),
-        "wg": ParamSpec((D, cfg.d_ff), ("fsdp", "tp"), dt),
-        "wu": ParamSpec((D, cfg.d_ff), ("fsdp", "tp"), dt),
-        "wd": ParamSpec((cfg.d_ff, D), ("tp", "fsdp"), dt),
+        "ln_attn": ParamSpec(nb + (A,), (None, None), dt, init_scale=0.0),
+        "wq": ParamSpec(nb + (A, q), (None, "fsdp", "tp"), dt),
+        "wk": ParamSpec(nb + (A, kv), (None, "fsdp", "tp"), dt),
+        "wv": ParamSpec(nb + (A, kv), (None, "fsdp", "tp"), dt),
+        "wo": ParamSpec(nb + (q, D), (None, "tp", "fsdp"), dt),
+        "ln_mlp": ParamSpec(nb + (D,), (None, None), dt, init_scale=0.0),
+        "w_gate_up": ParamSpec(nb + (D, 2 * F), (None, "fsdp", "tp"), dt),
+        "w_down": ParamSpec(nb + (F, D), (None, "tp", "fsdp"), dt),
     }
+    hybrid = {
+        "mlp_a": ParamSpec(nh + (D, r), (None, "fsdp", None), dt),
+        "mlp_b": ParamSpec(nh + (r, 2 * F), (None, None, "tp"), dt),
+        "linear": ParamSpec(nh + (D, D), (None, "fsdp", "tp"), dt),
+    }
+    if cfg.use_shared_attention_adapter:
+        for name, width in (("q", q), ("k", kv), ("v", kv)):
+            hybrid[f"{name}_a"] = ParamSpec(nh + (A, r), (None, "fsdp", None), dt)
+            hybrid[f"{name}_b"] = ParamSpec(nh + (r, width), (None, None, "tp"), dt)
     return {
         "embed": ParamSpec((cfg.vocab, D), ("tp", "fsdp"), dt),
         "ln_f": ParamSpec((D,), (None,), dt, init_scale=0.0),
-        "unembed": ParamSpec((D, cfg.vocab), ("fsdp", "tp"), dt),
         "mamba": mamba_block_specs(cfg, cfg.n_layers),
         "shared": shared,
+        "hybrid": hybrid,
     }
 
 
@@ -71,55 +93,36 @@ def init(cfg: ArchConfig, key) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _shared_fwd(cfg, mesh, rules, x, x0, sp, *, collect_kv: bool):
-    """Shared transformer block. x/x0: (B,S,D). Returns (x', (k,v)|None)."""
-    cdt = jnp.dtype(cfg.compute_dtype)
-    B, S, D = x.shape
-    dh, H, Hk = cfg.head_dim, cfg.n_heads, cfg.n_kv
-    u = jnp.concatenate([rms_norm(x, sp["ln1"], cfg.norm_eps), x0], axis=-1)
-    u = jnp.einsum("bsd,dk->bsk", u, sp["proj_in"].astype(cdt))
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    q = jnp.einsum("bsd,dk->bsk", u, sp["wq"].astype(cdt)).reshape(B, S, H, dh)
-    k = jnp.einsum("bsd,dk->bsk", u, sp["wk"].astype(cdt)).reshape(B, S, Hk, dh)
-    v = jnp.einsum("bsd,dk->bsk", u, sp["wv"].astype(cdt)).reshape(B, S, Hk, dh)
-    q = rope(q, positions, cfg.rope_theta)
-    kr = rope(k, positions, cfg.rope_theta)
-    attn = chunked_attention(
-        q, kr, v, causal=True,
-        q_chunk=min(256, S), kv_chunk=min(256, S),
-    )
-    o = jnp.einsum("bsk,kd->bsd", attn.reshape(B, S, -1), sp["wo"].astype(cdt))
-    x = constrain(x + o, rules, "dp", "sp", None)
-    h = rms_norm(x, sp["ln2"], cfg.norm_eps)
-    g = jnp.einsum("bsd,df->bsf", h, sp["wg"].astype(cdt))
-    uu = jnp.einsum("bsd,df->bsf", h, sp["wu"].astype(cdt))
-    f = jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * uu, sp["wd"].astype(cdt))
-    x = constrain(x + f, rules, "dp", "sp", None)
-    return x, ((kr, v) if collect_kv else None)
+def _at(tree, i):
+    return jax.tree.map(lambda p: p[i], tree)
 
 
-def _shared_decode(cfg, mesh, rules, x, x0, sp, kc, vc, cur_index, dec):
+def _shared_block(cfg: ArchConfig, params, k: int, x, x0, attend):
+    """The k-th hybrid layer's shared block over (..., D) activations:
+    returns (linear_k(t), what ``attend`` returned beside its output).
+    ``attend(q, k, v)`` runs the attention core on the flat projections:
+    over the sequence in prefill, against the lane cache in decode."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    B, D = x.shape
-    dh, H, Hk = cfg.head_dim, cfg.n_heads, cfg.n_kv
-    u = jnp.concatenate([rms_norm(x, sp["ln1"], cfg.norm_eps), x0], axis=-1)
-    u = jnp.einsum("bd,dk->bk", u, sp["proj_in"].astype(cdt))
-    q = jnp.einsum("bd,dk->bk", u, sp["wq"].astype(cdt)).reshape(B, H, dh)
-    k = jnp.einsum("bd,dk->bk", u, sp["wk"].astype(cdt)).reshape(B, Hk, dh)
-    v = jnp.einsum("bd,dk->bk", u, sp["wv"].astype(cdt)).reshape(B, Hk, dh)
-    # scalar (aligned batch) or (B,) vector (slotted serve: per-lane
-    # positions) — decode_attention handles both
-    pos = decode_positions(cur_index, B)
-    q = rope(q[:, None], pos, cfg.rope_theta)[:, 0].reshape(B, Hk, H // Hk, dh)
-    k = rope(k[:, None], pos, cfg.rope_theta)[:, 0]
-    attn, kc, vc = decode_attention(q, kc, vc, k, v, cur_index, sharding=dec)
-    o = jnp.einsum("bk,kd->bd", attn.reshape(B, H * dh), sp["wo"].astype(cdt))
-    x = x + o
-    h = rms_norm(x, sp["ln2"], cfg.norm_eps)
-    g = jnp.einsum("bd,df->bf", h, sp["wg"].astype(cdt))
-    uu = jnp.einsum("bd,df->bf", h, sp["wu"].astype(cdt))
-    f = jnp.einsum("bf,fd->bd", jax.nn.silu(g) * uu, sp["wd"].astype(cdt))
-    return x + f, kc, vc
+    sp = _at(params["shared"], k % cfg.num_mem_blocks)
+    hp = _at(params["hybrid"], k)
+    mm = lambda a, w: jnp.einsum("...d,dk->...k", a, w.astype(cdt))
+    with jax.named_scope("zamba.shared_block"):
+        u = rms_norm(jnp.concatenate([x, x0], axis=-1), sp["ln_attn"],
+                     cfg.norm_eps)
+
+        def proj(name):
+            y = mm(u, sp[f"w{name}"])
+            if cfg.use_shared_attention_adapter:
+                y = y + mm(mm(u, hp[f"{name}_a"]), hp[f"{name}_b"])
+            return y
+
+        a, extra = attend(proj("q"), proj("k"), proj("v"))
+        h = rms_norm(mm(a, sp["wo"]), sp["ln_mlp"], cfg.norm_eps)
+        gate, up = jnp.split(
+            mm(h, sp["w_gate_up"]) + mm(mm(h, hp["mlp_a"]), hp["mlp_b"]), 2,
+            axis=-1)
+        t = mm(ACTIVATIONS[cfg.gate_act](gate) * up, sp["w_down"])
+        return mm(t, hp["linear"]), extra
 
 
 def _embed(cfg, params, tokens):
@@ -127,11 +130,16 @@ def _embed(cfg, params, tokens):
     return jnp.take(params["embed"].astype(cdt), tokens, axis=0)
 
 
+def _logits(cfg, params, x):
+    cdt = jnp.dtype(cfg.compute_dtype)
+    return jnp.einsum("...d,vd->...v", x, params["embed"].astype(cdt))
+
+
 def forward(cfg, mesh, rules, params, tokens, *, remat=True, collect=False,
             plen=None):
     """Returns (hidden, cache dict or None): with ``collect=True`` the
-    second element is ``{"k", "v", "ssm", "conv"}`` — the shared block's
-    stacked KV plus the mamba final states — else ``None``.
+    second element is ``{"k", "v", "ssm", "conv"}`` — each hybrid layer's
+    KV stacked plus every layer's Mamba2 final states — else ``None``.
 
     ``plen`` (traced scalar, slot-serving prefill only): positions beyond
     it are right-padding of a length bucket.  The attention KV of padded
@@ -146,43 +154,59 @@ def forward(cfg, mesh, rules, params, tokens, *, remat=True, collect=False,
         x = jnp.where(valid[..., None], x, 0.0)  # pad activations stay finite
     x0 = x
     x = constrain(x, rules, "dp", "sp", None)
-    segs = _segments(cfg)
-    kvs, states = [], []
-    off = 0
-    for n in segs:
-        x, kv = _shared_fwd(cfg, mesh, rules, x, x0, params["shared"], collect_kv=collect)
-        kvs.append(kv)
-        seg_bp = jax.tree.map(lambda p: p[off:off + n], params["mamba"])
+    B, S = tokens.shape
+    H, Hk, dh = cfg.n_heads, cfg.n_kv, cfg.head_dim
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-        def body(x, bp):
+    def attend(q, k, v):
+        q = rope(q.reshape(B, S, H, dh), positions, cfg.rope_theta) * q_scale(cfg)
+        k = rope(k.reshape(B, S, Hk, dh), positions, cfg.rope_theta)
+        v = v.reshape(B, S, Hk, dh)
+        with jax.named_scope("attention"):
+            o = chunked_attention(q, k, v, causal=True,
+                                  q_chunk=min(256, S), kv_chunk=min(256, S))
+        return o.reshape(B, S, H * dh), (k, v)
+
+    def mamba(x, bp, add=None):
+        with jax.named_scope("zamba.mamba"):
             if collect:
-                x, st = mamba_block_fwd(cfg, rules, x, bp, return_state=True,
-                                        valid=valid, state_len=plen)
-                return x, st
-            return mamba_block_fwd(cfg, rules, x, bp), None
+                return mamba_block_fwd(cfg, rules, x, bp, return_state=True,
+                                       valid=valid, state_len=plen, add=add)
+            return mamba_block_fwd(cfg, rules, x, bp, add=add), None
 
-        from .common import remat_wrap
-        body = remat_wrap(body, remat)
-        x, st = jax.lax.scan(body, x, seg_bp)
-        states.append(st)
-        off += n
+    def hybrid(x, k, bp):
+        lt, kv = _shared_block(cfg, params, k, x, x0, attend)
+        x, st = mamba(x, bp, lt)
+        return x, (kv, st)
+
+    kvs, states = [], []
+    for a, b, k in runs(cfg):
+        if k is not None:
+            x, (kv, st) = remat_wrap(lambda x, bp, k=k: hybrid(x, k, bp),
+                                     remat)(x, _at(params["mamba"], a))
+            kvs.append(kv)
+            states.append(jax.tree.map(lambda s: s[None], st))
+            a += 1
+        if b > a:
+            seg = jax.tree.map(lambda p: p[a:b], params["mamba"])
+            x, st = jax.lax.scan(remat_wrap(mamba, remat), x, seg)
+            states.append(st)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    if collect:
-        ssm = jnp.concatenate([s[0] for s in states], axis=0)
-        conv = jnp.concatenate([s[1] for s in states], axis=0)
-        k = jnp.stack([kv[0] for kv in kvs])
-        v = jnp.stack([kv[1] for kv in kvs])
-        return x, {"k": k, "v": v, "ssm": ssm, "conv": conv}
-    return x, None
+    if not collect:
+        return x, None
+    return x, {
+        "k": jnp.stack([kv[0] for kv in kvs]),
+        "v": jnp.stack([kv[1] for kv in kvs]),
+        "ssm": jnp.concatenate([s[0] for s in states], axis=0),
+        "conv": jnp.concatenate([s[1] for s in states], axis=0),
+    }
 
 
 def loss_fn(cfg, mesh, rules, params, batch, *, remat=True):
     tokens = batch["tokens"]
     inp, labels = tokens[:, :-1], tokens[:, 1:]
     hidden, _ = forward(cfg, mesh, rules, params, inp, remat=remat)
-    cdt = jnp.dtype(cfg.compute_dtype)
-    logits = jnp.einsum("bsd,dv->bsv", hidden, params["unembed"].astype(cdt))
-    logits = constrain(logits, rules, "dp", None, "tp")
+    logits = constrain(_logits(cfg, params, hidden), rules, "dp", None, "tp")
     loss = cross_entropy_loss(logits, labels)
     return loss, {"ce_loss": loss, "lb_loss": jnp.float32(0.0),
                   "drop_frac": jnp.float32(0.0)}
@@ -193,9 +217,9 @@ def loss_fn(cfg, mesh, rules, params, batch, *, remat=True):
 # ---------------------------------------------------------------------------
 
 # serve-engine state kind: each lane carries BOTH a slotted KV segment
-# (the shared attention block, seq axis, lazily-overwritten) and per-lane
-# recurrent mamba leaves (no seq axis, hard-reset) — the engine composes
-# the two through one cache dict
+# (the hybrid layers' attention, seq axis, lazily-overwritten) and
+# per-lane recurrent mamba leaves (no seq axis, hard-reset) — the engine
+# composes the two through one cache dict
 STATE_KIND = "hybrid"
 
 
@@ -207,17 +231,16 @@ def recurrent_leaf_axes(cfg: ArchConfig) -> dict:
 
 def lane_leaf_axes(cfg: ArchConfig) -> dict:
     """All slot-cache leaves a lane owns (host-tier spill/restore unit):
-    the slotted KV segment (lane axis 1, after the shared-invocation
-    axis) plus the recurrent mamba leaves."""
+    the slotted KV segment (lane axis 1, after the hybrid-layer axis)
+    plus the recurrent mamba leaves."""
     return {"k": 1, "v": 1, **recurrent_leaf_axes(cfg)}
 
 
 def make_cache_specs(cfg: ArchConfig, batch: int, max_len: int):
-    ns = n_shared_invocations(cfg)
     ms = mamba_state_specs(cfg, cfg.n_layers, batch)
     kv = jax.ShapeDtypeStruct(
-        (ns, batch, max_len, cfg.n_kv, cfg.head_dim), jnp.dtype(cfg.compute_dtype)
-    )
+        (len(cfg.hybrid_layer_ids), batch, max_len, cfg.n_kv, cfg.head_dim),
+        jnp.dtype(cfg.compute_dtype))
     return {"k": kv, "v": kv, "ssm": ms["ssm"], "conv": ms["conv"]}
 
 
@@ -246,54 +269,68 @@ def prefill(cfg, mesh, rules, params, tokens, img_embeds=None, *, max_len=None):
 
     cache["k"], cache["v"] = pad(cache["k"]), pad(cache["v"])
     specs = cache_pspec(cfg, dec)
-    from .common import constrain_spec
     cache = {n: constrain_spec(c, mesh, specs[n]) for n, c in cache.items()}
-    cdt = jnp.dtype(cfg.compute_dtype)
-    logits = jnp.einsum("bd,dv->bv", hidden[:, -1], params["unembed"].astype(cdt))
-    return cache, logits
+    return cache, _logits(cfg, params, hidden[:, -1])
 
 
 def decode_step(cfg, mesh, rules, params, cache, tokens, cur_index):
     x = _embed(cfg, params, tokens[:, None])[:, 0]
     x0 = x
-    dec = DecodeSharding.choose(mesh, tokens.shape[0])
-    segs = _segments(cfg)
-    k_out, v_out, ssm_out, conv_out = [], [], [], []
-    off = 0
-    for si, n in enumerate(segs):
-        x, kc, vc = _shared_decode(
-            cfg, mesh, rules, x, x0, params["shared"],
-            cache["k"][si], cache["v"][si], cur_index, dec,
-        )
-        k_out.append(kc); v_out.append(vc)
-        seg_bp = jax.tree.map(lambda p: p[off:off + n], params["mamba"])
-        seg_ssm = cache["ssm"][off:off + n]
-        seg_conv = cache["conv"][off:off + n]
+    B = tokens.shape[0]
+    H, Hk, dh = cfg.n_heads, cfg.n_kv, cfg.head_dim
+    dec = DecodeSharding.choose(mesh, B)
+    # scalar (aligned batch) or (B,) vector (slotted serve: per-lane
+    # positions) — decode_attention handles both
+    pos = decode_positions(cur_index, B)
 
-        def body(x, xs):
-            bp, s_ssm, s_conv = xs
-            x, s_ssm, s_conv = mamba_block_decode(cfg, rules, x, bp, s_ssm, s_conv)
-            return x, (s_ssm, s_conv)
+    def attend_at(kc, vc):
+        def attend(q, k, v):
+            q = rope(q.reshape(B, 1, H, dh), pos, cfg.rope_theta)[:, 0] * q_scale(cfg)
+            k = rope(k.reshape(B, 1, Hk, dh), pos, cfg.rope_theta)[:, 0]
+            with jax.named_scope("attention"):
+                o, kc2, vc2 = decode_attention(
+                    q.reshape(B, Hk, H // Hk, dh), kc, vc, k,
+                    v.reshape(B, Hk, dh), cur_index, sharding=dec)
+            return o.reshape(B, H * dh), (kc2, vc2)
+        return attend
 
-        x, (new_ssm, new_conv) = jax.lax.scan(body, x, (seg_bp, seg_ssm, seg_conv))
-        ssm_out.append(new_ssm); conv_out.append(new_conv)
-        off += n
+    def mamba(x, xs, add=None):
+        bp, s_ssm, s_conv = xs
+        with jax.named_scope("zamba.mamba"):
+            x, s_ssm, s_conv = mamba_block_decode(cfg, rules, x, bp, s_ssm,
+                                                  s_conv, add)
+        return x, (s_ssm, s_conv)
+
+    kvs, states = [], []
+    for a, b, k in runs(cfg):
+        if k is not None:
+            lt, kv = _shared_block(cfg, params, k, x, x0,
+                                   attend_at(cache["k"][k], cache["v"][k]))
+            kvs.append(kv)
+            x, st = mamba(x, (_at(params["mamba"], a), cache["ssm"][a],
+                              cache["conv"][a]), lt)
+            states.append(jax.tree.map(lambda s: s[None], st))
+            a += 1
+        if b > a:
+            xs = (jax.tree.map(lambda p: p[a:b], params["mamba"]),
+                  cache["ssm"][a:b], cache["conv"][a:b])
+            x, st = jax.lax.scan(mamba, x, xs)
+            states.append(st)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    cdt = jnp.dtype(cfg.compute_dtype)
-    logits = jnp.einsum("bd,dv->bv", x, params["unembed"].astype(cdt))
     new_cache = {
-        "k": jnp.stack(k_out), "v": jnp.stack(v_out),
-        "ssm": jnp.concatenate(ssm_out, axis=0),
-        "conv": jnp.concatenate(conv_out, axis=0),
+        "k": jnp.stack([kv[0] for kv in kvs]),
+        "v": jnp.stack([kv[1] for kv in kvs]),
+        "ssm": jnp.concatenate([s[0] for s in states], axis=0),
+        "conv": jnp.concatenate([s[1] for s in states], axis=0),
     }
-    return logits, new_cache
+    return _logits(cfg, params, x), new_cache
 
 
 def prefill_slot(cfg, mesh, rules, params, cache, tokens, slot, plen):
     """Prefill ONE prompt into lane ``slot`` of the composed hybrid cache.
 
     tokens: (1, S_bucket) right-padded; ``plen``/``slot`` traced scalars.
-    The lane write covers both state kinds at once: the shared block's
+    The lane write covers both state kinds at once: the hybrid layers'
     K/V land in the lane's seq slice ``[0, S_bucket)`` (padded tail inert
     by causality + lazy overwrite, exactly the lm slotted argument) and
     the mamba ``ssm``/``conv`` leaves land as the lane's O(1) recurrent
@@ -311,6 +348,4 @@ def prefill_slot(cfg, mesh, rules, params, cache, tokens, slot, plen):
 
     cache = {name: write(cache[name], col[name]) for name in cache}
     last = jax.lax.dynamic_index_in_dim(hidden, plen - 1, 1, keepdims=False)
-    cdt = jnp.dtype(cfg.compute_dtype)
-    logits = jnp.einsum("bd,dv->bv", last, params["unembed"].astype(cdt))
-    return cache, logits
+    return cache, _logits(cfg, params, last)

@@ -473,6 +473,13 @@ class ServeEngine:
                 "draft_params passed but EngineConfig.spec_draft is None")
         self._state_sds = state_sds(self.state)
         self.kv_reserved_bytes = cache_nbytes(self.state["cache"])
+        # what the lanes hold, by lifecycle: the KV segment and the
+        # recurrent leaves (registry.recurrent_leaf_axes)
+        rec_bytes = cache_nbytes([self.state["cache"][n]
+                                  for n in self.rec.leaf_axes])
+        self.obs.metrics.gauge("engine.kv_bytes").set(
+            self.kv_reserved_bytes - rec_bytes)
+        self.obs.metrics.gauge("engine.recurrent_bytes").set(rec_bytes)
 
         # --- host-RAM tier ---------------------------------------------
         # paged engines spill per-block (the lane's KV, block by block);

@@ -67,6 +67,7 @@ def test_full_config_matches_assignment(arch):
         "gemma2-27b": (46, 4608, 32, 16, 36_864, 256_000),
         "internvl2-76b": (80, 8192, 64, 8, 28_672, 128_256),
         "zamba2-1.2b": (38, 2048, 32, 32, 8192, 32_000),
+        "zamba2-7b": (81, 3584, 32, 32, 14_336, 32_000),
         "whisper-tiny": (4, 384, 6, 6, 1536, 51_865),
         "xlstm-1.3b": (48, 2048, 4, 4, 0, 50_304),
         "qwen3-moe-30b-a3b": (48, 2048, 32, 4, 0, 151_936),
@@ -79,6 +80,11 @@ def test_full_config_matches_assignment(arch):
         assert cfg.moe.d_expert == (768 if "30b" in arch else 1536)
     if arch == "zamba2-1.2b":
         assert cfg.ssm.state == 64 and cfg.subquadratic
+    if arch == "zamba2-7b":
+        assert cfg.ssm.state == 64 and cfg.ssm.n_groups == 2
+        assert cfg.n_heads * cfg.head_dim == 2 * cfg.d_model == 7168
+        assert cfg.num_mem_blocks == 2 and cfg.adapter_rank == 128
+        assert cfg.hybrid_layer_ids[:2] == (6, 11) and len(cfg.hybrid_layer_ids) == 13
     if arch == "gemma2-27b":
         assert cfg.alt_local_global and cfg.attn_softcap == 50.0 \
             and cfg.logit_softcap == 30.0

@@ -198,10 +198,12 @@ def test_fuzz_cross_engine_parity(setup):
 # without preemptions: resume re-prefills the prompt through the SAME
 # bucket executable and replays decode, so parity is bitwise by
 # construction — any divergence is a real requeue/replay/zeroing bug.
-# Runs a slice of the main episode budget (two extra families per episode).
+# Runs a slice of the main episode budget (extra families per episode).
+# Zamba runs at both structures: one shared block with attention adapters
+# (1.2B) and two alternating blocks over two SSD groups (7B).
 
 REC_EPISODES = max(2, EPISODES // 10)
-REC_ARCHS = ("xlstm-1.3b", "zamba2-1.2b")
+REC_ARCHS = ("xlstm-1.3b", "zamba2-1.2b", "zamba2-7b")
 
 
 @pytest.fixture(scope="module", params=REC_ARCHS)

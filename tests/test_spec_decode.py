@@ -38,7 +38,8 @@ from test_engine_fuzz import (
     drive_chaos, make_stream, spec_modes,
 )
 
-REC_ARCHS = ("xlstm-1.3b", "zamba2-1.2b")
+# zamba at both of its structures (one shared block / two alternating)
+REC_ARCHS = ("xlstm-1.3b", "zamba2-1.2b", "zamba2-7b")
 
 
 @pytest.fixture(scope="module")

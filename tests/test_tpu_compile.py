@@ -109,3 +109,53 @@ def test_flash_attention_grad_compiles_at_smollm_train_shape(one_chip):
     grad = _compile(jax.grad(loss, (0, 1, 2)), q, kv, kv).as_text()
     names = set(re.findall(r"%(flash_attention_\w+?)(?:\.\d+)? = ", grad))
     assert names == {"flash_attention_fwd", "flash_attention_bwd"}, names
+
+
+def test_zamba2_serve_programs_fit_one_chip(one_chip):
+    """The serve cell's decode step over every lane and its largest
+    prefill bucket, at the cut's published widths with fp32 weights, for
+    a described v5e: the compiler refuses a program that overflows the
+    chip's memory, so a change that makes the cell overflow fails here.
+    Both programs carry the model's named scopes."""
+    import json
+    import pathlib
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from bench import harness
+    from repro.models import registry
+    from repro.models.common import ShardRules
+    from repro.serve.cache import slot_state_specs
+    from repro.serve.step import slot_decode_program, slot_prefill_program
+    from repro.train.step import shardings_for
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cell = harness.load_cell("serve-zamba2-7b-longdoc", root=root)
+    cfg = cell.adapter().arch(cell.config)
+    lanes, max_len = int(cell.traffic["lanes"]), int(cell.traffic["max_len"])
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1, 1),
+                ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
+    rules = ShardRules.for_mesh(mesh)
+    p_sh = shardings_for(mesh, registry.param_pspecs(cfg, rules))
+    p_sds = registry.abstract_params(cfg)
+    sds, sh = slot_state_specs(cfg, mesh, lanes, max_len)
+    rep = NamedSharding(mesh, P())
+    i32 = lambda shape=(): jax.ShapeDtypeStruct(shape, jnp.int32)
+    f32 = jax.ShapeDtypeStruct((), jnp.float32)
+
+    decode = jax.jit(slot_decode_program(cfg, mesh, rules),
+                     in_shardings=(p_sh, sh), out_shardings=(sh, rep),
+                     donate_argnums=(1,)).lower(p_sds, sds).compile()
+    prefill = jax.jit(slot_prefill_program(cfg, mesh, rules),
+                      in_shardings=(p_sh, sh) + (rep,) * 7,
+                      out_shardings=(sh, rep), donate_argnums=(1,)).lower(
+        p_sds, sds, i32((1, max_len)), i32(), i32(), i32(), f32, i32(),
+        f32).compile()
+    weights = sum(int(np.prod(s.shape)) * 4 for s in jax.tree.leaves(p_sds))
+    assert weights > 7e9                        # 1.76 B fp32 parameters
+    for compiled in (decode, prefill):
+        assert compiled.memory_analysis().argument_size_in_bytes > weights
+        text = compiled.as_text()
+        for scope in ("zamba.shared_block", "zamba.mamba", "zamba.ssd"):
+            assert f"/{scope}/" in text, scope
