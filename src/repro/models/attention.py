@@ -10,6 +10,9 @@ the oracle it is tested against.
 the ``data``/``pod``) mesh axes; each shard computes a partial softmax and
 the results are combined with a log-sum-exp reduction (distributed
 flash-decoding).  This is what makes 32k/500k-token caches fit.
+``decode_attention_pos_minor`` is the same step over a cache stored with
+the position axis minor-most, the slotted layout of the lm and hybrid
+families.
 """
 from __future__ import annotations
 
@@ -324,6 +327,96 @@ def decode_attention(
     return fn(q, k_cache, v_cache, k_new, v_new, cur_index)
 
 
+def decode_attention_pos_minor(
+    q, k_cache, v_cache, k_new, v_new, cur_index, *,
+    sharding: DecodeSharding,
+    window: int = 0,
+    softcap: float = 0.0,
+):
+    """One decoding step against one layer of a position-minor KV cache —
+    the slotted serve cache of the lm and hybrid families.
+
+    q:               (B, Hk, rep, D) — current-token queries (RoPE applied)
+    k_cache/v_cache: (B, Hk, D, S) — the position axis minor-most,
+                     sharded over ``sharding.seq_axes``
+    k_new/v_new:     (B, Hk, D) — current token's K/V
+    cur_index:       scalar or ``(B,)`` — tokens already in each lane
+
+    The TPU tiles the two minor dims of an array; with the position axis
+    minor-most a (Hk, D, S) lane is stored unpadded and both dots read it
+    where it lies.  (In (S, Hk, D) order the (Hk, D) = (5, 64) tile pads
+    3.2x and XLA re-lays the whole layer out around every step.)
+
+    The dots attend the cached positions ``< cur_index``; the new token's
+    own score and value are merged by the log-sum-exp rule — the same
+    softmax over positions ``<= cur_index``, in fp32, as
+    :func:`decode_attention`.  So the dots do not wait on the token
+    write, a masked select that XLA fuses into the caller's in-place
+    ``dynamic_update_slice`` of the layer into its donated stack.
+
+    Returns (out (B, Hk, rep, D), k_cache', v_cache').
+    """
+    mesh = sharding.mesh
+    baxes, saxes = sharding.batch_axes, sharding.seq_axes
+    B, S = q.shape[0], k_cache.shape[-1]
+    n_seq = int(np.prod([mesh.shape[a] for a in saxes])) if saxes else 1
+    s_loc = S // n_seq
+    idx = jnp.broadcast_to(jnp.asarray(cur_index, jnp.int32), (B,))
+    scale = q.shape[-1] ** -0.5
+
+    def scores(q, k):
+        s = jnp.einsum("bhrd,bhd...->bhr...", q, k) * scale
+        return softcap * jnp.tanh(s / softcap) if softcap else s
+
+    def shard_fn(q, kc, vc, kn, vn, idx):
+        # local shapes: q (Bl, Hk, rep, D); kc/vc (Bl, Hk, D, s_loc)
+        start = jax.lax.axis_index(saxes) * s_loc if saxes else 0
+        pos = start + jnp.arange(s_loc)
+        valid = pos[None, :] < idx[:, None]                # (Bl, s_loc)
+        if window:
+            valid &= pos[None, :] > idx[:, None] - window
+        qf = q.astype(jnp.float32)
+        s = jnp.where(valid[:, None, None, :],
+                      scores(qf, kc.astype(jnp.float32)), NEG_INF)
+        # a lane with no cached position in range keeps m finite, so its
+        # masked scores give p = 0 and the new token alone carries it
+        m = jnp.maximum(jnp.max(s, axis=-1), -1e30)
+        p = jnp.exp(s - m[..., None])
+        l = jnp.sum(p, axis=-1)
+        o = jnp.einsum("bhrs,bhds->bhrd", p, vc.astype(jnp.float32))
+        if saxes:
+            m_g = jax.lax.pmax(m, saxes)
+            corr = jnp.exp(m - m_g)
+            l = jax.lax.psum(l * corr, saxes)
+            o = jax.lax.psum(o * corr[..., None], saxes)
+            m = m_g
+
+        # the new token, rounded to the cache's dtype as it is stored
+        kn, vn = kn.astype(kc.dtype), vn.astype(vc.dtype)
+        s_new = scores(qf, kn.astype(jnp.float32))         # (Bl, Hk, rep)
+        m_all = jnp.maximum(m, s_new)
+        a, b = jnp.exp(m - m_all), jnp.exp(s_new - m_all)
+        o = o * a[..., None] + b[..., None] * vn.astype(jnp.float32)[:, :, None]
+        out = (o / (l * a + b)[..., None]).astype(q.dtype)
+
+        at = (pos[None, :] == idx[:, None])[:, None, None, :]
+        kc = jnp.where(at, kn[..., None], kc)
+        vc = jnp.where(at, vn[..., None], vc)
+        return out, kc, vc
+
+    b = baxes or None
+    s_sp = saxes or None
+    kv_spec = P(b, None, None, s_sp)
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh,
+        in_specs=(P(b, None, None, None), kv_spec, kv_spec,
+                  P(b, None, None), P(b, None, None), P(b)),
+        out_specs=(P(b, None, None, None), kv_spec, kv_spec),
+        check_vma=False,
+    )
+    return fn(q, k_cache, v_cache, k_new, v_new, idx)
+
+
 # ---------------------------------------------------------------------------
 # Paged KV cache: block-table plumbing + paged decode
 # ---------------------------------------------------------------------------
@@ -413,9 +506,9 @@ def paged_decode_attention(
     lengths:       (B,) int32 — tokens already in each lane
     tables:        (B, nb) int32 — the lanes' block-table rows
     impl:          "ref" gathers lanes and runs the masked-softmax XLA
-                   path (bitwise-identical to the slotted
-                   ``decode_attention`` on equal inputs — the parity
-                   anchor); "pallas" dispatches the block-walking kernel
+                   path (bitwise-identical to
+                   ``decode_attention`` on equal inputs); "pallas"
+                   dispatches the block-walking kernel
                    (kernels/paged_attention) that never materialises the
                    gathered lanes.
 

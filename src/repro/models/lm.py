@@ -21,7 +21,7 @@ from repro.configs.base import ArchConfig
 from .attention import (
     DecodeSharding,
     chunked_attention,
-    decode_attention,
+    decode_attention_pos_minor,
     paged_decode_attention,
     paged_gather,
     paged_write_positions,
@@ -243,8 +243,8 @@ def _block_decode(cfg, mesh, rules, x, bp, kc, vc, cur_index, *, window: int,
 
     ``attn_fn(q, kc, vc, k_new, v_new, window)`` overrides the cache-write
     + attention step (the paged path); the default is the slotted
-    ``decode_attention`` under ``dec_sharding``.  Everything around the
-    attention call is shared so the layouts stay numerically identical.
+    position-minor ``decode_attention_pos_minor`` under ``dec_sharding``.
+    Everything around the attention call is shared between the layouts.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     dh, H, Hk = cfg.head_dim, cfg.n_heads, cfg.n_kv
@@ -261,7 +261,7 @@ def _block_decode(cfg, mesh, rules, x, bp, kc, vc, cur_index, *, window: int,
     k = rope(k[:, None], pos, cfg.rope_theta)[:, 0]
     q = q.reshape(B, Hk, H // Hk, dh)
     if attn_fn is None:
-        attn, kc, vc = decode_attention(
+        attn, kc, vc = decode_attention_pos_minor(
             q, kc, vc, k, v, cur_index,
             sharding=dec_sharding, window=window, softcap=cfg.attn_softcap,
         )
@@ -380,9 +380,12 @@ def loss_fn(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, params, batch,
 
 
 def make_cache_specs(cfg: ArchConfig, batch: int, max_len: int):
-    """Abstract KV cache (lead..., B, S, Hk, dh) as ShapeDtypeStructs."""
+    """Abstract slotted KV cache (lead..., B, Hk, dh, S) as
+    ShapeDtypeStructs.  The position axis is minor-most, so the TPU stores
+    a lane's (Hk, dh, S) unpadded and decode reads and writes it in place
+    (see ``attention.decode_attention_pos_minor``)."""
     lead = _leading(cfg)
-    shape = lead + (batch, max_len, cfg.n_kv, cfg.head_dim)
+    shape = lead + (batch, cfg.n_kv, cfg.head_dim, max_len)
     c = jax.ShapeDtypeStruct(shape, jnp.dtype(cfg.compute_dtype))
     return {"k": c, "v": c}
 
@@ -399,7 +402,7 @@ def lane_leaf_axes(cfg: ArchConfig) -> dict:
 def cache_pspec(cfg: ArchConfig, dec: DecodeSharding):
     lead = (None,) * len(_leading(cfg))
     from jax.sharding import PartitionSpec as P
-    spec = P(*lead, dec.batch_axes or None, dec.seq_axes or None, None, None)
+    spec = P(*lead, dec.batch_axes or None, None, None, dec.seq_axes or None)
     return {"k": spec, "v": spec}
 
 
@@ -454,17 +457,17 @@ def prefill(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, params, tokens,
         cfg, mesh, rules, params, tokens, img_embeds,
         remat=False, collect_kv=True,
     )
-    k, v = kv                                   # (L[,2], B, S, Hk, dh)
     dec = DecodeSharding.choose(mesh, tokens.shape[0])
 
-    def pad(c):
-        if max_len and max_len > c.shape[-3]:
+    def pad(c):                     # (L[,2], B, S, Hk, dh) -> cache layout
+        c = jnp.moveaxis(c, -3, -1)
+        if max_len and max_len > c.shape[-1]:
             pad_width = [(0, 0)] * c.ndim
-            pad_width[-3] = (0, max_len - c.shape[-3])
+            pad_width[-1] = (0, max_len - c.shape[-1])
             c = jnp.pad(c, pad_width)
         return c
 
-    cache = {"k": pad(k), "v": pad(v)}
+    cache = {"k": pad(kv[0]), "v": pad(kv[1])}
     specs = cache_pspec(cfg, dec)
     from .common import constrain_spec
     cache = {
@@ -489,14 +492,14 @@ def prefill_slot(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, params,
     hidden, _, kv = forward(
         cfg, mesh, rules, params, tokens, None, remat=False, collect_kv=True,
     )
-    k, v = kv                                   # (L[,2], 1, S_bucket, Hk, dh)
     lead = len(_leading(cfg))
 
-    def write(c, new):
+    def write(c, new):              # new: (L[,2], 1, S_bucket, Hk, dh)
+        new = jnp.moveaxis(new, -3, -1).astype(c.dtype)
         start = (0,) * lead + (slot, 0, 0, 0)
-        return jax.lax.dynamic_update_slice(c, new.astype(c.dtype), start)
+        return jax.lax.dynamic_update_slice(c, new, start)
 
-    cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
+    cache = {"k": write(cache["k"], kv[0]), "v": write(cache["v"], kv[1])}
     last = jax.lax.dynamic_index_in_dim(hidden, plen - 1, 1, keepdims=False)
     return cache, unembed(cfg, rules, params, last)
 
@@ -617,38 +620,54 @@ def prefill_chunk_paged(cfg: ArchConfig, mesh: Mesh, rules: ShardRules,
     return {"k": k_new, "v": v_new}, unembed(cfg, rules, params, last)
 
 
+def _layer(a, at):
+    """The layer of a stacked cache leaf at leading indices ``at``."""
+    n = len(at)
+    return jax.lax.dynamic_slice(
+        a, tuple(at) + (0,) * (a.ndim - n), (1,) * n + a.shape[n:],
+    ).reshape(a.shape[n:])
+
+
+def _set_layer(a, layer, at):
+    """Write ``layer`` back at leading indices ``at`` (in place on a
+    donated or loop-carried buffer)."""
+    n = len(at)
+    return jax.lax.dynamic_update_slice(
+        a, layer.reshape((1,) * n + layer.shape), tuple(at) + (0,) * layer.ndim)
+
+
 def _decode_walk(cfg, mesh, rules, params, cache, x, cur_index, dec, attn_fn):
     """Shared per-layer decode walk for the slotted and paged layouts.
 
     fori_loop with in-place dynamic updates on the carried cache: the
     stacked KV cache lives in ONE buffer (a scan's xs+ys would
-    double-buffer it — 2x HBM for the dominant decode tensor).  The
-    leading layer axis is unsharded, so the per-layer slice/update is
-    local (no collectives).
+    double-buffer it — 2x HBM for the dominant decode tensor).  Each
+    sub-layer's slice goes back to its own place, so nothing restacks
+    the (local, global) pair.  The leading layer axes are unsharded, so
+    the per-layer slice/update is local (no collectives).
     """
     windows = _windows(cfg)
 
     def body(i, carry):
         x, kc_all, vc_all = carry
-        idx = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-        bp = jax.tree.map(idx, params["blocks"])
-        kc, vc = idx(kc_all), idx(vc_all)
-        if len(windows) > 1:
-            kcs, vcs = [], []
-            for j, w in enumerate(windows):
-                x, kcj, vcj = _block_decode(
-                    cfg, mesh, rules, x, _sub(bp, j), kc[j], vc[j], cur_index,
-                    window=w, dec_sharding=dec, attn_fn=attn_fn,
-                )
-                kcs.append(kcj); vcs.append(vcj)
-            kc, vc = jnp.stack(kcs), jnp.stack(vcs)
-        else:
+        # each layer's weights plus a zero XLA can neither fold nor hoist:
+        # without it XLA converts all layers' weights to the compute dtype
+        # ahead of the loop and holds every converted copy as temporaries
+        # (629 MB at smollm-360m); with it, one layer's at a time
+        zero = jax.lax.optimization_barrier(jnp.float32(0) * i)
+        bp = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+            + zero.astype(a.dtype), params["blocks"])
+        for j, w in enumerate(windows):
+            at = (i, j) if len(windows) > 1 else (i,)
             x, kc, vc = _block_decode(
-                cfg, mesh, rules, x, bp, kc, vc, cur_index,
-                window=windows[0], dec_sharding=dec, attn_fn=attn_fn,
+                cfg, mesh, rules, x, _sub(bp, j) if len(windows) > 1 else bp,
+                _layer(kc_all, at), _layer(vc_all, at), cur_index,
+                window=w, dec_sharding=dec, attn_fn=attn_fn,
             )
-        upd = lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, i, 0)
-        return x, upd(kc_all, kc), upd(vc_all, vc)
+            kc_all = _set_layer(kc_all, kc, at)
+            vc_all = _set_layer(vc_all, vc, at)
+        return x, kc_all, vc_all
 
     L = jax.tree.leaves(params["blocks"])[0].shape[0]
     x, k_new, v_new = jax.lax.fori_loop(

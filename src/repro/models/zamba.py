@@ -28,7 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from .attention import DecodeSharding, chunked_attention, decode_attention, rope
+from .attention import (
+    DecodeSharding, chunked_attention, decode_attention_pos_minor, rope,
+)
 from .common import (
     ACTIVATIONS, ParamSpec, ShardRules, constrain, constrain_spec,
     cross_entropy_loss, decode_positions, init_tree, remat_wrap, rms_norm,
@@ -139,7 +141,8 @@ def forward(cfg, mesh, rules, params, tokens, *, remat=True, collect=False,
             plen=None):
     """Returns (hidden, cache dict or None): with ``collect=True`` the
     second element is ``{"k", "v", "ssm", "conv"}`` — each hybrid layer's
-    KV stacked plus every layer's Mamba2 final states — else ``None``.
+    KV stacked in the cache's (nh, B, Hk, dh, S) layout plus every layer's
+    Mamba2 final states — else ``None``.
 
     ``plen`` (traced scalar, slot-serving prefill only): positions beyond
     it are right-padding of a length bucket.  The attention KV of padded
@@ -194,9 +197,10 @@ def forward(cfg, mesh, rules, params, tokens, *, remat=True, collect=False,
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     if not collect:
         return x, None
+    pos_minor = lambda i: jnp.moveaxis(jnp.stack([kv[i] for kv in kvs]), 2, -1)
     return x, {
-        "k": jnp.stack([kv[0] for kv in kvs]),
-        "v": jnp.stack([kv[1] for kv in kvs]),
+        "k": pos_minor(0),
+        "v": pos_minor(1),
         "ssm": jnp.concatenate([s[0] for s in states], axis=0),
         "conv": jnp.concatenate([s[1] for s in states], axis=0),
     }
@@ -237,9 +241,11 @@ def lane_leaf_axes(cfg: ArchConfig) -> dict:
 
 
 def make_cache_specs(cfg: ArchConfig, batch: int, max_len: int):
+    """The hybrid layers' KV (nh, B, Hk, dh, S), position minor-most as
+    in ``lm.make_cache_specs``, beside the Mamba2 leaves."""
     ms = mamba_state_specs(cfg, cfg.n_layers, batch)
     kv = jax.ShapeDtypeStruct(
-        (len(cfg.hybrid_layer_ids), batch, max_len, cfg.n_kv, cfg.head_dim),
+        (len(cfg.hybrid_layer_ids), batch, cfg.n_kv, cfg.head_dim, max_len),
         jnp.dtype(cfg.compute_dtype))
     return {"k": kv, "v": kv, "ssm": ms["ssm"], "conv": ms["conv"]}
 
@@ -249,8 +255,8 @@ def cache_pspec(cfg: ArchConfig, dec: DecodeSharding):
     b = dec.batch_axes or None
     s = dec.seq_axes or None
     return {
-        "k": P(None, b, s, None, None),
-        "v": P(None, b, s, None, None),
+        "k": P(None, b, None, None, s),
+        "v": P(None, b, None, None, s),
         "ssm": P(None, b, None, None, None),
         "conv": P(None, b, None, None),
     }
@@ -261,9 +267,9 @@ def prefill(cfg, mesh, rules, params, tokens, img_embeds=None, *, max_len=None):
     dec = DecodeSharding.choose(mesh, tokens.shape[0])
 
     def pad(c):
-        if max_len and max_len > c.shape[2]:
+        if max_len and max_len > c.shape[-1]:
             pw = [(0, 0)] * c.ndim
-            pw[2] = (0, max_len - c.shape[2])
+            pw[-1] = (0, max_len - c.shape[-1])
             c = jnp.pad(c, pw)
         return c
 
@@ -280,7 +286,7 @@ def decode_step(cfg, mesh, rules, params, cache, tokens, cur_index):
     H, Hk, dh = cfg.n_heads, cfg.n_kv, cfg.head_dim
     dec = DecodeSharding.choose(mesh, B)
     # scalar (aligned batch) or (B,) vector (slotted serve: per-lane
-    # positions) — decode_attention handles both
+    # positions) — decode_attention_pos_minor handles both
     pos = decode_positions(cur_index, B)
 
     def attend_at(kc, vc):
@@ -288,7 +294,7 @@ def decode_step(cfg, mesh, rules, params, cache, tokens, cur_index):
             q = rope(q.reshape(B, 1, H, dh), pos, cfg.rope_theta)[:, 0] * q_scale(cfg)
             k = rope(k.reshape(B, 1, Hk, dh), pos, cfg.rope_theta)[:, 0]
             with jax.named_scope("attention"):
-                o, kc2, vc2 = decode_attention(
+                o, kc2, vc2 = decode_attention_pos_minor(
                     q.reshape(B, Hk, H // Hk, dh), kc, vc, k,
                     v.reshape(B, Hk, dh), cur_index, sharding=dec)
             return o.reshape(B, H * dh), (kc2, vc2)
@@ -301,12 +307,15 @@ def decode_step(cfg, mesh, rules, params, cache, tokens, cur_index):
                                                   s_conv, add)
         return x, (s_ssm, s_conv)
 
-    kvs, states = [], []
+    # each hybrid layer's KV slice goes back to its place in the donated
+    # stack (an in-place update; restacking would copy the whole leaf)
+    kc_all, vc_all = cache["k"], cache["v"]
+    states = []
     for a, b, k in runs(cfg):
         if k is not None:
-            lt, kv = _shared_block(cfg, params, k, x, x0,
-                                   attend_at(cache["k"][k], cache["v"][k]))
-            kvs.append(kv)
+            lt, (kc, vc) = _shared_block(cfg, params, k, x, x0,
+                                         attend_at(kc_all[k], vc_all[k]))
+            kc_all, vc_all = kc_all.at[k].set(kc), vc_all.at[k].set(vc)
             x, st = mamba(x, (_at(params["mamba"], a), cache["ssm"][a],
                               cache["conv"][a]), lt)
             states.append(jax.tree.map(lambda s: s[None], st))
@@ -318,8 +327,8 @@ def decode_step(cfg, mesh, rules, params, cache, tokens, cur_index):
             states.append(st)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     new_cache = {
-        "k": jnp.stack([kv[0] for kv in kvs]),
-        "v": jnp.stack([kv[1] for kv in kvs]),
+        "k": kc_all,
+        "v": vc_all,
         "ssm": jnp.concatenate([s[0] for s in states], axis=0),
         "conv": jnp.concatenate([s[1] for s in states], axis=0),
     }

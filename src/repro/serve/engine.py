@@ -609,7 +609,12 @@ class ServeEngine:
                 out_shardings=(self._state_sh, self._rep),
                 donate_argnums=(1,),
             )
-            return jitted.lower(self._params_sds, self._state_sds).compile()
+            exe = jitted.lower(self._params_sds, self._state_sds).compile()
+            # the step's scratch beside the donated state: what a cache
+            # re-laid out or restacked every step would hold
+            self.obs.metrics.gauge("engine.decode_temp_bytes").set(
+                exe.memory_analysis().temp_size_in_bytes)
+            return exe
 
         return self.aot.get(key, build)
 

@@ -280,6 +280,49 @@ def check_elastic():
         assert res2["final_loss"] < res["final_loss"] + 1.0
 
 
+
+def check_decode_seq_sharded():
+    """The position-minor decode step with its cache sharded over the
+    sequence (and the lanes over data) equals the unsharded step: the
+    shards' partial softmaxes and the new token merge exactly."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+    from repro.models.attention import (
+        DecodeSharding, decode_attention_pos_minor)
+
+    Hk, rep, D, S = 2, 3, 8, 32
+    one = jax.make_mesh((1, 1, 1), ("pod", "data", "model"),
+                        axis_types=(AxisType.Auto,) * 3,
+                        devices=jax.devices()[:1])
+    eight = jax.make_mesh((1, 2, 4), ("pod", "data", "model"),
+                          axis_types=(AxisType.Auto,) * 3)
+    rng = np.random.default_rng(0)
+    for B, idx in ((2, np.array([0, S - 1])), (2, np.array([13, 20])),
+                   (1, np.array(S - 9))):
+        f = lambda *shape: rng.normal(size=shape).astype(np.float32)
+        args = (f(B, Hk, rep, D), f(B, Hk, D, S), f(B, Hk, D, S),
+                f(B, Hk, D), f(B, Hk, D), idx.astype(np.int32))
+
+        def run(mesh):
+            dec = DecodeSharding.choose(mesh, B)
+            kv = NamedSharding(mesh, P(dec.batch_axes or None, None, None,
+                                       dec.seq_axes or None))
+            q, kc, vc, kn, vn, cur = args
+            kc, vc = jax.device_put(kc, kv), jax.device_put(vc, kv)
+            fn = jax.jit(lambda *a: decode_attention_pos_minor(
+                *a, sharding=dec, window=11, softcap=20.0))
+            return dec, [np.asarray(x) for x in fn(q, kc, vc, kn, vn, cur)]
+
+        dec, got = run(eight)
+        assert dec.seq_axes, dec
+        _, want = run(one)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
+        for g, w in zip(got[1:], want[1:]):
+            np.testing.assert_array_equal(g, w)
+
+
 CHECKS = {
     "scatter_reduce": check_scatter_reduce,
     "indexing": check_indexing,
@@ -289,6 +332,7 @@ CHECKS = {
     "elastic": check_elastic,
     "bucketed_reduce": check_bucketed_reduce,
     "flat_parity": check_flat_parity,
+    "decode_seq_sharded": check_decode_seq_sharded,
 }
 
 

@@ -6,8 +6,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.models.attention import (
-    DecodeSharding, chunked_attention, decode_attention, pick_chunk,
-    reference_attention, rope,
+    DecodeSharding, chunked_attention, decode_attention,
+    decode_attention_pos_minor, pick_chunk, reference_attention, rope,
 )
 
 
@@ -105,3 +105,31 @@ def test_decode_matches_reference_chain(mesh, window):
         np.testing.assert_allclose(
             np.asarray(out).reshape(B, 1, H, D), np.asarray(ref),
             atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("window,softcap", [(0, 0.0), (5, 0.0), (0, 20.0),
+                                            (5, 20.0)])
+@pytest.mark.parametrize("vector", [True, False])
+def test_pos_minor_decode_matches_decode_attention(mesh, window, softcap,
+                                                    vector):
+    """The position-minor step attends and writes what ``decode_attention``
+    does on the same cache in (B, S, Hk, D) order: lanes at 0, mid and
+    the last position, garbage beyond each length, window and softcap."""
+    B, Hk, rep, D, S = 4, 2, 3, 8, 16
+    rng = np.random.default_rng(7)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    q, kc, vc = f(B, Hk, rep, D), f(B, S, Hk, D), f(B, S, Hk, D)
+    kn, vn = f(B, Hk, D), f(B, Hk, D)
+    idx = jnp.asarray([0, 6, S - 1, 11], jnp.int32) if vector \
+        else jnp.int32(S - 1)
+    kw = dict(sharding=DecodeSharding.choose(mesh, B), window=window,
+              softcap=softcap)
+    want, wk, wv = jax.jit(lambda *a: decode_attention(*a, **kw))(
+        q, kc, vc, kn, vn, idx)
+    pm = lambda c: jnp.transpose(c, (0, 2, 3, 1))          # (B, Hk, D, S)
+    got, gk, gv = jax.jit(lambda *a: decode_attention_pos_minor(*a, **kw))(
+        q, pm(kc), pm(vc), kn, vn, idx)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-6, rtol=3e-6)
+    np.testing.assert_array_equal(np.asarray(gk), np.asarray(pm(wk)))
+    np.testing.assert_array_equal(np.asarray(gv), np.asarray(pm(wv)))

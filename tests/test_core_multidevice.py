@@ -37,6 +37,10 @@ def test_sgd_parity_with_serial_program():
     run_check("sgd_parity")
 
 
+def test_seq_sharded_pos_minor_decode():
+    run_check("decode_seq_sharded")
+
+
 def test_elastic_restore():
     """Checkpoint from a dp=8 mesh restores and trains on a dp=4xtp=2 mesh."""
     run_check("elastic")

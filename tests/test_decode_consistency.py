@@ -56,3 +56,61 @@ def test_prefill_then_decode_matches_forward(arch, mesh, rules, key):
         np.asarray(logits_d), np.asarray(logits_b),
         atol=2e-3, rtol=2e-3,
     ), arch
+
+
+@pytest.mark.parametrize("arch", ["smollm-360m", "gemma2-27b"])
+def test_slotted_decode_position_minor_matches_before(arch, mesh, rules, key):
+    """One slotted decode step in the (L[,2], B, Hk, dh, S) cache, lanes at
+    lengths 0, mid and max_len - 1 over garbage beyond each length (gemma2:
+    windowed local layers, softcaps): the logits, greedy tokens and
+    written cache of the (B, S, Hk, dh) path — ``decode_attention`` on
+    each layer transposed — and the full forward's logits there."""
+    from repro.models import lm
+    from repro.models.attention import DecodeSharding, decode_attention
+
+    cfg = dataclasses.replace(get_smoke_config(arch), compute_dtype="float32")
+    params = lm.init(cfg, key)
+    S, lens = 32, [0, 9, 31]
+    rng = np.random.default_rng(11)
+    seqs = [rng.integers(0, cfg.vocab, n + 1).astype(np.int32) for n in lens]
+    cache = {name: jnp.asarray(rng.normal(size=sd.shape), sd.dtype)
+             for name, sd in lm.make_cache_specs(cfg, len(lens), S).items()}
+    prefill = jax.jit(lambda c, t, slot, plen: lm.prefill_slot(
+        cfg, mesh, rules, params, c, t, slot, plen))
+    for lane, n in enumerate(lens):
+        if n:
+            prompt = np.zeros((1, S), np.int32)
+            prompt[0, :n] = seqs[lane][:n]
+            cache, _ = prefill(cache, jnp.asarray(prompt), lane, n)
+    toks = jnp.asarray([s[n] for s, n in zip(seqs, lens)], jnp.int32)
+    cur = jnp.asarray(lens, jnp.int32)
+    got, got_cache = jax.jit(lambda c: lm.decode_step(
+        cfg, mesh, rules, params, c, toks, cur))(cache)
+
+    dec = DecodeSharding.choose(mesh, len(lens))
+    bshd = lambda c: jnp.moveaxis(c, -1, 1)            # (B, S, Hk, dh)
+
+    def before(q, kc, vc, k, v, window):
+        o, kc, vc = decode_attention(q, bshd(kc), bshd(vc), k, v, cur,
+                                     sharding=dec, window=window,
+                                     softcap=cfg.attn_softcap)
+        return o, jnp.moveaxis(kc, 1, -1), jnp.moveaxis(vc, 1, -1)
+
+    def walk_before(c):
+        x = lm.embed_tokens(cfg, rules, params, toks[:, None])[:, 0]
+        return lm._decode_walk(cfg, mesh, rules, params, c, x, cur, None,
+                               before)
+
+    want, want_cache = jax.jit(walk_before)(cache)
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(got_cache[name]),
+                                   np.asarray(want_cache[name]),
+                                   atol=1e-5, rtol=1e-5)
+    fwd = jax.jit(lambda t: lm.unembed(cfg, rules, params, lm.forward(
+        cfg, mesh, rules, params, t, remat=False)[0][:, -1]))
+    for lane, seq in enumerate(seqs):
+        np.testing.assert_allclose(got[lane], np.asarray(fwd(seq[None]))[0],
+                                   atol=2e-3, rtol=2e-3)

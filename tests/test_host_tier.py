@@ -327,6 +327,42 @@ def test_preempted_lane_restores_without_replay(setups):
     assert eng.counters["restored_bytes"] > 0
 
 
+def test_slotted_lane_spill_round_trips_position_minor(setups):
+    """A slotted lane's spill is its (L, Hk, dh, S) slice of each KV leaf,
+    position minor-most; preempted mid-decode and restored from it, the
+    lane finishes the never-preempted stream with nothing replayed, and
+    its prompt's KV on the device is the payload's."""
+    cfg, mesh, rules, params, aot = setups["smollm-360m"]
+    stream = [(0, np.arange(1, 13, dtype=np.int32), 8)]
+    want = drive_ref(setups, "slotted", stream)
+    eng = ServeEngine(cfg, mesh, rules, params, LAYOUTS["slotted"], aot=aot)
+    spills = []
+
+    def capture(slot, _capture=eng._capture_spill):
+        spills.append(_capture(slot))
+        return spills[-1]
+
+    eng._capture_spill = capture
+
+    def hook(eng, tick):
+        if tick == 3 and eng.slots[0] is not None \
+                and eng.slots[0].generated >= 2:
+            eng.preempt(0)
+
+    assert _drive(eng, stream, hook=hook) == want
+    assert eng.counters["spills"] == 1 and eng.counters["restores"] == 1
+    assert eng.counters["replayed_tokens"] == 0
+    (sp,) = spills
+    shape = (cfg.n_layers, cfg.n_kv, cfg.head_dim, MAX_LEN)
+    assert sp.kind == "lane"
+    assert {k: v.shape for k, v in sp.leaves.items()} == {"k": shape,
+                                                          "v": shape}
+    plen = stream[0][1].size
+    for name, leaf in sp.leaves.items():
+        lane = np.asarray(eng.state["cache"][name])[:, 0]
+        np.testing.assert_array_equal(lane[..., :plen], leaf[..., :plen])
+
+
 def test_host_tier_second_level_prefix_cache(setups):
     """LRU-reclaimed prefix chains spill to host and later admissions
     promote them back — the prompt's prefill is skipped even though the

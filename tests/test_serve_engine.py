@@ -116,6 +116,21 @@ def test_put_copies_host_mirrors(setup):
         assert not np.asarray(dev).any(), dtype
 
 
+def test_decode_temp_bytes_gauge_set_at_build(setup):
+    """``engine.decode_temp_bytes`` is the compiled decode executable's
+    temporaries, set once when the engine builds it."""
+    cfg, mesh, rules, params = setup
+    eng = ServeEngine(cfg, mesh, rules, params,
+                      EngineConfig(max_slots=2, max_len=32))
+    gauge = eng.obs.metrics.gauge("engine.decode_temp_bytes")
+    assert gauge.value == 0
+    exe = eng._decode_exe()
+    assert gauge.value == exe.memory_analysis().temp_size_in_bytes > 0
+    eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=3)
+    eng.drain()
+    assert gauge.value == exe.memory_analysis().temp_size_in_bytes
+
+
 def test_staggered_matches_solo_static(setup):
     """THE continuous-batching correctness property: a request admitted
     mid-flight into a slot (at its own cache position, prompt padded to a

@@ -111,42 +111,95 @@ def test_flash_attention_grad_compiles_at_smollm_train_shape(one_chip):
     assert names == {"flash_attention_fwd", "flash_attention_bwd"}, names
 
 
-def test_zamba2_serve_programs_fit_one_chip(one_chip):
-    """The serve cell's decode step over every lane and its largest
-    prefill bucket, at the cut's published widths with fp32 weights, for
-    a described v5e: the compiler refuses a program that overflows the
-    chip's memory, so a change that makes the cell overflow fails here.
-    Both programs carry the model's named scopes."""
-    import json
+def _serve_cell(one_chip, name):
+    """A serve cell's configuration, lanes and abstract engine state on
+    the described chip: (cfg, mesh, rules, params sds, params shardings,
+    state sds, state shardings, max_len)."""
     import pathlib
 
     import numpy as np
-    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, Mesh
 
     from bench import harness
     from repro.models import registry
     from repro.models.common import ShardRules
     from repro.serve.cache import slot_state_specs
-    from repro.serve.step import slot_decode_program, slot_prefill_program
     from repro.train.step import shardings_for
 
     root = pathlib.Path(__file__).resolve().parents[1]
-    cell = harness.load_cell("serve-zamba2-7b-longdoc", root=root)
+    cell = harness.load_cell(name, root=root)
     cfg = cell.adapter().arch(cell.config)
     lanes, max_len = int(cell.traffic["lanes"]), int(cell.traffic["max_len"])
     mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1, 1),
                 ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
     rules = ShardRules.for_mesh(mesh)
     p_sh = shardings_for(mesh, registry.param_pspecs(cfg, rules))
-    p_sds = registry.abstract_params(cfg)
     sds, sh = slot_state_specs(cfg, mesh, lanes, max_len)
+    return (cfg, mesh, rules, registry.abstract_params(cfg), p_sh, sds, sh,
+            max_len)
+
+
+def _decode(cell):
+    """The engine's decode executable over every lane of the cell."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.serve.step import slot_decode_program
+
+    cfg, mesh, rules, p_sds, p_sh, sds, sh, _ = cell
+    return jax.jit(slot_decode_program(cfg, mesh, rules),
+                   in_shardings=(p_sh, sh),
+                   out_shardings=(sh, NamedSharding(mesh, P())),
+                   donate_argnums=(1,)).lower(p_sds, sds).compile()
+
+
+def _kv_relayouts(text, leaf_shape):
+    """Instructions that copy, transpose, concatenate or scatter a whole
+    KV leaf or one layer of it, whatever the order of its dims."""
+    kv = {tuple(sorted(d for d in shape if d > 1))
+          for shape in (leaf_shape, leaf_shape[1:])}
+    found = re.finditer(
+        r"%\S+ = \w+\[([\d,]*)\](?:\{[^}]*\})? "
+        r"(?:copy|transpose|concatenate|scatter)\(", text)
+    return [m.group(0) for m in found
+            if tuple(sorted(int(d) for d in m.group(1).split(",")
+                            if d and int(d) > 1)) in kv]
+
+
+def test_chat_decode_updates_the_kv_cache_in_place(one_chip):
+    """The chat cell's decode step (64 lanes of 2048) for a described
+    v5e: the position-minor cache is attended where it lies and the token
+    written in place, so no whole leaf or layer of it is copied, re-laid
+    out, restacked or scattered, and the step's temporaries stay small."""
+    cell = _serve_cell(one_chip, "serve-smollm-360m-chat")
+    cache = cell[5]["cache"]
+    decode = _decode(cell)
+    assert _kv_relayouts(decode.as_text(), cache["k"].shape) == []
+    memory = decode.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.5e9
+    # the donated cache leaves come back in place
+    assert memory.alias_size_in_bytes >= sum(
+        s.size * s.dtype.itemsize for s in cache.values())
+
+
+def test_zamba2_serve_programs_fit_one_chip(one_chip):
+    """The serve cell's decode step over every lane and its largest
+    prefill bucket, at the cut's published widths with fp32 weights, for
+    a described v5e: the compiler refuses a program that overflows the
+    chip's memory, so a change that makes the cell overflow fails here.
+    Both programs carry the model's named scopes, and decode neither
+    copies nor restacks the hybrid layers' KV."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.serve.step import slot_prefill_program
+
+    cell = _serve_cell(one_chip, "serve-zamba2-7b-longdoc")
+    cfg, mesh, rules, p_sds, p_sh, sds, sh, max_len = cell
     rep = NamedSharding(mesh, P())
     i32 = lambda shape=(): jax.ShapeDtypeStruct(shape, jnp.int32)
     f32 = jax.ShapeDtypeStruct((), jnp.float32)
 
-    decode = jax.jit(slot_decode_program(cfg, mesh, rules),
-                     in_shardings=(p_sh, sh), out_shardings=(sh, rep),
-                     donate_argnums=(1,)).lower(p_sds, sds).compile()
+    decode = _decode(cell)
     prefill = jax.jit(slot_prefill_program(cfg, mesh, rules),
                       in_shardings=(p_sh, sh) + (rep,) * 7,
                       out_shardings=(sh, rep), donate_argnums=(1,)).lower(
@@ -159,3 +212,4 @@ def test_zamba2_serve_programs_fit_one_chip(one_chip):
         text = compiled.as_text()
         for scope in ("zamba.shared_block", "zamba.mamba", "zamba.ssd"):
             assert f"/{scope}/" in text, scope
+    assert _kv_relayouts(decode.as_text(), sds["cache"]["k"].shape) == []

@@ -159,6 +159,40 @@ def test_slotted_prefill_then_decode_logits_match_reference(setup, mesh, rules):
                                atol=1e-4)
 
 
+def test_slotted_decode_at_mixed_lengths_matches_reference(setup, mesh,
+                                                          rules):
+    """One decode step of three lanes at lengths 0, 9 and max_len - 1 in
+    the position-minor KV segment, with garbage beyond each length: each
+    lane's logits and greedy token are the reference's at its position."""
+    ref, cfgd, cfg, w, params = setup
+    S, lens = 32, [0, 9, 31]
+    rng = np.random.default_rng(6)
+    seqs = [rng.integers(0, cfg.vocab, n + 1).astype(np.int32) for n in lens]
+    sds = zamba.make_cache_specs(cfg, len(lens), S)
+    assert sds["k"].shape == (2, len(lens), cfg.n_kv, cfg.head_dim, S)
+    cache = {n: jnp.asarray(rng.normal(size=sd.shape), sd.dtype)
+             if n in ("k", "v") else jnp.zeros(sd.shape, sd.dtype)
+             for n, sd in sds.items()}
+    prefill = jax.jit(lambda c, t, slot, plen: zamba.prefill_slot(
+        cfg, mesh, rules, params, c, t, slot, plen))
+    decode = jax.jit(lambda c, t, cur: zamba.decode_step(
+        cfg, mesh, rules, params, c, t, cur))
+    with jax.default_matmul_precision("highest"):
+        for lane, n in enumerate(lens):
+            if n:
+                prompt = np.zeros((1, S), np.int32)
+                prompt[0, :n] = seqs[lane][:n]
+                cache, _ = prefill(cache, jnp.asarray(prompt), lane, n)
+        got, _ = decode(
+            cache, jnp.asarray([s[n] for s, n in zip(seqs, lens)], jnp.int32),
+            jnp.asarray(lens, jnp.int32))
+        want = np.stack([np.asarray(ref.logits(cfgd, w, jnp.asarray(s[None])))
+                         [0, -1] for s in seqs])
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
 def test_engine_serves_the_reference_greedy_tokens(setup, mesh, rules):
     """ServeEngine prefill then decode, three requests through two lanes:
     every served token is the reference's best at its position, within
