@@ -25,15 +25,13 @@ class AotCache:
 
     ``stats["builds"]`` counts cache misses (one trace+compile each);
     ``stats["cache_hits"]`` counts steady-state dispatches.  A warmed-up
-    caller must show a flat ``builds`` counter — CI asserts this for the
-    serve engine (scripts/ci.sh) and the overlap bench tracks it for
-    ``SynkFunction``.
+    caller must show a flat ``builds`` counter — the tier-1 tests assert
+    this for the serve engine and for ``SynkFunction``.
 
     Every miss also records its lower+compile wall seconds in
     ``build_seconds`` (always wall time, even when the owning engine runs
     on a fake clock — compile cost is a real-world budget), and emits an
-    ``aot_build`` trace span when an ``obs`` handle is attached; see
-    ``top_builds`` for the slowest-builds report the serve bench embeds.
+    ``aot_build`` trace span when an ``obs`` handle is attached.
 
     Invariants: ``builds == len(self)`` (every miss stores exactly one
     entry, nothing is ever evicted); ``builds + cache_hits`` == total
@@ -74,13 +72,3 @@ class AotCache:
         else:
             self.stats["cache_hits"] += 1
         return entry
-
-    @property
-    def build_s_total(self) -> float:
-        return sum(self.build_seconds.values())
-
-    def top_builds(self, n: int = 5) -> list[tuple[str, float]]:
-        """The ``n`` slowest builds as (str(key), seconds), slowest first."""
-        ranked = sorted(self.build_seconds.items(),
-                        key=lambda kv: kv[1], reverse=True)
-        return [(str(k), round(s, 4)) for k, s in ranked[:n]]

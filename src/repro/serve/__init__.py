@@ -1,5 +1,4 @@
 from .cache import (
-    KeyMirror,
     RecurrentCache,
     bucket_for,
     make_slot_state,
@@ -44,7 +43,7 @@ __all__ = [
     "REPLICA_FAULT_SITES", "NONFINITE_TOKEN",
     "Router", "RouterConfig", "ReplicaHandle",
     "ServeConfig", "generate", "generate_static",
-    "KeyMirror", "RecurrentCache", "bucket_for", "make_slot_state",
+    "RecurrentCache", "bucket_for", "make_slot_state",
     "prompt_buckets", "slot_state_specs",
     "BlockAllocator", "HostTier", "LaneSpill", "SlotTables", "blocks_for",
     "check_tiered", "make_paged_state", "paged_state_specs", "prefix_keys",

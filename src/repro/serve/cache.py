@@ -99,7 +99,7 @@ class RecurrentCache:
       post-step ``active`` vector through :meth:`freeze`, which zeroes
       the recurrent leaves of every inactive lane *in the same
       executable* (a lane finishing on-device is zeroed in the step that
-      finishes it).  So after any fused-sampling step, an inactive
+      finishes it).  So after any such executable, an inactive
       lane's recurrent state is exactly zero — no stale recurrence ever
       advances, and no inf/NaN can accumulate in dead lanes.
     """
@@ -168,28 +168,6 @@ class RecurrentCache:
             if np.any(lanes != 0):
                 return False
         return True
-
-
-class KeyMirror:
-    """Host-side mirror of the device PRNG key stream.
-
-    Every serve executable (prefill chunk / decode step) splits
-    ``state["key"]`` exactly once per call.  In the host-sampling ablation
-    the sampler runs on the host from fetched logits, but draws its
-    randomness from this mirror — replaying the same splits in executable
-    order — so at a fixed engine seed the host path samples the *same*
-    tokens as the fused on-device sampler (asserted in
-    ``tests/test_serve_engine.py::test_host_vs_fused_sampler_parity``).
-    """
-
-    def __init__(self, seed: int):
-        self.key = jax.random.PRNGKey(seed).astype(jnp.uint32)
-
-    def split(self):
-        """Advance the stream one executable call; returns the subkey the
-        device-side program would have fed its sampler."""
-        self.key, sub = jax.random.split(self.key)
-        return sub
 
 
 def sched_specs(mesh, max_slots: int):

@@ -28,7 +28,7 @@ Two cache layouts (``EngineConfig.kv_layout``):
              reservation is ``num_blocks * page_size`` positions sized to
              load, not ``max_slots * max_len``.  Greedy decoding is
              token-for-token identical to the slotted layout (asserted in
-             tests and gated in CI).
+             tests and the cross-engine fuzzer).
 
 On the paged layout, **chunked prefill** (``EngineConfig.prefill_chunk``)
 admits long prompts as fixed-size chunks processed one per engine step and
@@ -38,8 +38,8 @@ the whole batch; one AOT executable per chunk size serves every prompt.
 Every executable is AOT-compiled once per static key through an
 :class:`~repro.core.aot.AotCache`, so steady-state dispatch is a dict
 probe: after warmup the engine's ``builds`` counter must stay flat
-(asserted by ``benchmarks/serve_bench.py --smoke`` in CI, for both
-layouts).
+(asserted by ``tests/test_paged.py::test_engine_steady_builds_flat`` for
+both layouts).
 
 Host-side the engine keeps a mirror of the scheduling vectors (lengths,
 budgets, block tables, which request owns which lane).  The mirror is
@@ -79,7 +79,6 @@ from repro.models.common import ShardRules
 from repro.train.step import shardings_for
 from .faults import NONFINITE_TOKEN, UNCOMMITTED, FaultPlan
 from .cache import (
-    KeyMirror,
     RecurrentCache,
     bucket_for,
     make_slot_state,
@@ -107,7 +106,6 @@ from .step import (
     paged_copy_program,
     paged_decode_program,
     paged_prefill_program,
-    sample_tokens,
     slot_decode_program,
     slot_prefill_program,
     spec_decode_program,
@@ -131,7 +129,7 @@ def _exact_share(total: int, units: int, denom: int) -> int:
 class EngineConfig:
     """Static engine configuration.
 
-    Fields that change a *lowered program* (shape/layout/sampler fusion)
+    Fields that change a *lowered program* (shape/layout/spec draft)
     are folded into the AOT cache key (``ServeEngine._sampler_key``), so
     engines differing there never share executables; host-side policies
     (``prefix_cache``, ``admission``) deliberately are not — they reuse
@@ -150,8 +148,6 @@ class EngineConfig:
     # prompt-length buckets for the prefill executables; None -> powers of
     # two up to max_len (one AOT build per bucket ever used)
     prefill_buckets: tuple[int, ...] | None = None
-    # False: benchmark baseline — logits round-trip to host sampling
-    fused_sampling: bool = True
     # --- KV layout -----------------------------------------------------
     kv_layout: str = "slotted"    # "slotted" | "paged"
     page_size: int = 16           # KV block size (paged)
@@ -204,7 +200,7 @@ class EngineConfig:
     # resamples from the target distribution.  Greedy verification is a
     # plain argmax comparison, so the committed stream is bitwise the
     # sequential engine's (asserted by the fuzzer across every state
-    # kind).  Requires fused_sampling; both fields set together.
+    # kind).  Both fields set together.
     spec_draft: Any = None
     spec_k: int = 0
 
@@ -298,8 +294,8 @@ class ServeEngine:
       refcount covers its mapping multiplicity; every written KV position
       lies inside its lane's mapped region.
     * Dispatch flatness: after :meth:`prebuild`, the AOT ``builds``
-      counter never grows (CI gates ``steady_builds_delta == 0``).
-    * Recurrent zeroing (non-``kv`` state kinds, fused sampling): an
+      counter never grows (asserted by the tier-1 tests).
+    * Recurrent zeroing (non-``kv`` state kinds): an
       inactive lane's recurrent leaves are exactly zero after the next
       executable runs — admission hard-resets, eviction zeroes.
     * Host mirror coherence: the scheduling vectors the host keeps are
@@ -354,10 +350,6 @@ class ServeEngine:
                 "spec_draft and spec_k must be set together "
                 f"(spec_draft={engine.spec_draft!r}, spec_k={engine.spec_k})")
         if self.spec:
-            if not engine.fused_sampling:
-                raise ValueError(
-                    "speculative decoding requires fused_sampling=True "
-                    "(the verify row rides the fused int32 token fetch)")
             if not registry.supports_slot_serving(engine.spec_draft):
                 raise ValueError(
                     f"draft family {engine.spec_draft.family!r} does not "
@@ -558,9 +550,6 @@ class ServeEngine:
         # deadline sweep is O(queue + slots) per step; skip it entirely
         # until some request actually carries a deadline
         self._has_deadlines = False
-        # host-sampling mode draws from a mirror of the device key stream
-        # so it samples the same tokens as the fused path at equal seed
-        self._key_mirror = KeyMirror(engine.seed)
         self._tok_mirror = np.zeros(engine.max_slots, np.int32)
         self._active_mirror = np.zeros(engine.max_slots, bool)
         self._active_dirty = False
@@ -580,7 +569,7 @@ class ServeEngine:
     def _sampler_key(self) -> tuple:
         e = self.econ
         return (self.cfg.name, e.max_slots, e.max_len, e.eos_id,
-                e.fused_sampling, e.kv_layout, e.page_size,
+                e.kv_layout, e.page_size,
                 self._num_blocks, e.paged_attn,
                 # spec changes the STATE SHAPE (the draft leaf), so every
                 # executable — not just the verify program — keys on it
@@ -594,12 +583,11 @@ class ServeEngine:
             if self.paged:
                 fn = paged_decode_program(
                     self.cfg, self.mesh, self.rules, eos_id=e.eos_id,
-                    fused=e.fused_sampling, impl=e.paged_attn,
+                    impl=e.paged_attn,
                 )
             else:
                 fn = slot_decode_program(
                     self.cfg, self.mesh, self.rules, eos_id=e.eos_id,
-                    fused=e.fused_sampling,
                 )
             jitted = jax.jit(
                 fn, in_shardings=(self._p_sh, self._state_sh),
@@ -688,7 +676,7 @@ class ServeEngine:
             if self.paged:
                 fn = paged_prefill_program(
                     self.cfg, self.mesh, self.rules, eos_id=e.eos_id,
-                    fused=e.fused_sampling, first=first,
+                    first=first,
                 )
                 jitted = jax.jit(
                     fn,
@@ -702,7 +690,6 @@ class ServeEngine:
                 ).compile()
             fn = slot_prefill_program(
                 self.cfg, self.mesh, self.rules, eos_id=e.eos_id,
-                fused=e.fused_sampling,
             )
             jitted = jax.jit(
                 fn,
@@ -1154,7 +1141,7 @@ class ServeEngine:
         executable — bitwise for KV *and* recurrent kinds) and replays
         the emitted tokens through decode, so the resumed stream is
         bitwise the unpreempted one (asserted for the ssm family in
-        tests and the serve bench)."""
+        tests)."""
         if self.slots[slot] is None:
             raise ValueError(f"slot {slot} is not serving a request")
         self._preempt(slot)
@@ -1711,7 +1698,6 @@ class ServeEngine:
                 self._put(s.temperature, jnp.float32),
                 self._put(s.top_k, jnp.int32), self._put(s.top_p, jnp.float32),
             )
-        sub = None if self.econ.fused_sampling else self._key_mirror.split()
         s.prefilled = end
         self._last_op = "prefill"
         self.counters["prefill_chunks"] += 1
@@ -1725,22 +1711,12 @@ class ServeEngine:
             # full prompt (draft state is never restored, always rebuilt)
             self._spec_draft_prefill(slot, s.prompt)
 
-        if self.econ.fused_sampling:
-            tok = int(np.asarray(out)[0])
-        else:
-            logits = np.asarray(out)
-            tok = int(self._host_sample(
-                logits, sub, np.array([s.temperature]),
-                np.array([s.top_k]), np.array([s.top_p]))[0])
-            if not np.isfinite(logits).all():
-                tok = NONFINITE_TOKEN   # host-side twin of the fused sentinel
+        tok = int(np.asarray(out)[0])
         if tok == NONFINITE_TOKEN:
             # the prompt's sampling position saw non-finite logits:
             # quarantine + bounded retry (or terminal "failed")
             self.counters["faults_detected"] += 1
             self._retry_lane(slot, "non-finite logits at prefill")
-            if not self.econ.fused_sampling:
-                self._writeback_sampled()
             return
         now = self.clock()
         comp = self.live[s.rid]
@@ -1770,8 +1746,6 @@ class ServeEngine:
             self._active_mirror[slot] = not done
             if done:
                 self._finish(slot, now)
-        if not self.econ.fused_sampling:
-            self._writeback_sampled()
 
     def _observe_terminal(self, comp: Completion) -> None:
         """Latency histograms + the request's terminal trace mark — the
@@ -1898,23 +1872,6 @@ class ServeEngine:
             if s is not None and s.deadline is not None \
                     and now >= s.deadline:
                 self._terminate(slot, "timeout", now=now)
-
-    def _host_sample(self, logits, sub, temps, top_ks, top_ps) -> np.ndarray:
-        """Benchmark baseline: sample on host from full (M, V) logits with
-        the SAME fused sampler math (temperature + per-row top-k/top-p)
-        and a subkey from the device key-stream mirror — at a fixed seed
-        the ablation reproduces the fused path token-for-token."""
-        return np.asarray(sample_tokens(
-            jnp.asarray(logits, jnp.float32), sub,
-            jnp.asarray(temps, jnp.float32),
-            top_ks=jnp.asarray(top_ks, jnp.int32),
-            top_ps=jnp.asarray(top_ps, jnp.float32),
-        ))
-
-    def _writeback_sampled(self) -> None:
-        """Host-sampling mode: push tokens/active back to device state."""
-        self.state["tokens"] = self._put(self._tok_mirror, jnp.int32)
-        self.state["active"] = self._put(self._active_mirror, jnp.bool_)
 
     def _note_kv_usage(self, decoding: frozenset = frozenset()) -> None:
         """Update the cache-usage high-water mark.  Paged reads the
@@ -2099,8 +2056,6 @@ class ServeEngine:
                 exe = self._decode_exe()
                 self.state, out = exe(self.params, self.state)
             self._last_op = "decode"
-            sub = None if self.econ.fused_sampling \
-                else self._key_mirror.split()
             self._note_kv_usage(frozenset(active_slots))
             self.counters["decode_steps"] += 1
             self.counters["dead_slot_steps"] += (
@@ -2108,8 +2063,8 @@ class ServeEngine:
             if dispatch_sid is not None:
                 self.obs.end(dispatch_sid)
                 fetch_sid = self.obs.begin("decode.fetch", track=self._track)
-            # the one per-step host sync: (max_slots, k+1) verify rows,
-            # sampled tokens, or logits for host sampling
+            # the one per-step host sync: (max_slots, k+1) verify rows
+            # or sampled tokens
             fetched = np.asarray(out)
             if dispatch_sid is not None:
                 self.obs.end(fetch_sid)
@@ -2117,21 +2072,8 @@ class ServeEngine:
                 rows = fetched
                 self.counters["spec_steps"] += 1
                 self.counters["spec_rounds"] += len(active_slots)
-            elif self.econ.fused_sampling:
-                toks = fetched
             else:
-                arr = lambda f, d, dt: np.array([
-                    f(s) if s is not None else d for s in self.slots
-                ], dtype=dt)
-                logits = fetched
-                toks = self._host_sample(
-                    logits, sub,
-                    arr(lambda s: s.temperature, 0.0, np.float32),
-                    arr(lambda s: s.top_k, 0, np.int32),
-                    arr(lambda s: s.top_p, 0.0, np.float32))
-                toks = np.where(
-                    np.isfinite(logits).all(axis=-1), toks,
-                    np.int32(NONFINITE_TOKEN))  # host twin of the sentinel
+                toks = fetched
             if sid is not None:
                 self.obs.end(sid)
                 advance_sid = self.obs.begin("engine.advance",
@@ -2187,8 +2129,6 @@ class ServeEngine:
             else:
                 for i in active_slots:
                     self._advance_lane(i, int(toks[i]), now)
-            if not self.econ.fused_sampling:
-                self._writeback_sampled()
             progressed = True
         self._note_kv_usage()
         if advance_sid is not None:
@@ -2530,8 +2470,7 @@ class ServeEngine:
                 f"parked rid {rid} is not a resume pending")
         for slot, q in enumerate(self._quarantine):
             assert 0 <= q <= 1, f"slot {slot}: quarantine {q} out of range"
-        if self.rec and self.econ.fused_sampling \
-                and self._last_op == "decode":
+        if self.rec and self._last_op == "decode":
             free = [i for i, s in enumerate(self.slots) if s is None]
             assert self.rec.lanes_are_zero(self.state["cache"], free), (
                 f"an evicted lane in {free} holds non-zero recurrent state")

@@ -11,7 +11,7 @@ the AOT dispatch cache.
 every sequence decodes to the full token budget with logits round-tripping
 to host sampling each step.  It remains as the fallback for families the
 slot engine doesn't cover (modality frontends with extra inputs) and as
-the benchmark baseline.
+the reference the engine's greedy parity tests compare against.
 """
 from __future__ import annotations
 

@@ -148,19 +148,14 @@ def sample_tokens(logits, key, temps, top_k: int = 0, top_ks=None, top_ps=None):
 # ---------------------------------------------------------------------------
 
 
-def _decode_program(decode_fn, *, eos_id: int | None, fused: bool,
-                    freeze=None):
+def _decode_program(decode_fn, *, eos_id: int | None, freeze=None):
     """Wrap a cache-layout-specific ``decode_fn(params, state) ->
     (logits, cache')`` with the shared scheduling/sampling bookkeeping.
 
-    fused=True (the engine default): ``fn(params, state) -> (state', tok)``
-    — sampling (per-slot temperature/top-k/top-p vectors), length
-    bookkeeping, and EOS/budget eviction all happen on device; ``tok`` is
+    ``fn(params, state) -> (state', tok)`` — sampling (per-slot
+    temperature/top-k/top-p vectors), length bookkeeping, and EOS/budget
+    eviction all happen on device; ``tok`` (``(max_slots,)`` int32) is
     the only per-step host fetch.
-
-    fused=False (benchmark baseline): ``fn(params, state) -> (state', logits)``
-    — full logits round-trip to the host, which samples and writes
-    ``tokens``/``active`` back before the next step (the old loop's cost).
 
     ``freeze(cache, active) -> cache`` (recurrent state kinds): applied to
     the post-step cache with the post-step ``active`` vector, zeroing the
@@ -173,13 +168,6 @@ def _decode_program(decode_fn, *, eos_id: int | None, fused: bool,
         logits, cache = decode_fn(params, state)
         active = state["active"]
         new_len = state["lengths"] + active.astype(jnp.int32)
-        if not fused:
-            # host sampling: eviction lands by host push before the next
-            # step, so inactive lanes zero one executable later
-            if freeze is not None:
-                cache = freeze(cache, active | state["replay"])
-            new_state = {**state, "cache": cache, "lengths": new_len, "key": key}
-            return new_state, logits
         tok = sample_tokens(
             logits, sub, state["temps"],
             top_ks=state["top_ks"], top_ps=state["top_ps"],
@@ -211,7 +199,7 @@ def _decode_program(decode_fn, *, eos_id: int | None, fused: bool,
 
 
 def slot_decode_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
-                        eos_id: int | None = None, fused: bool = True):
+                        eos_id: int | None = None):
     """One decode step over every lane of the slotted cache.
 
     Family-generic: ``mod.decode_step`` advances a KV cache (lm), a pure
@@ -230,13 +218,12 @@ def slot_decode_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
             state["tokens"], state["lengths"],
         )
 
-    return _decode_program(decode_fn, eos_id=eos_id, fused=fused,
+    return _decode_program(decode_fn, eos_id=eos_id,
                            freeze=rec.freeze if rec else None)
 
 
 def paged_decode_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
-                         eos_id: int | None = None, fused: bool = True,
-                         impl: str = "ref"):
+                         eos_id: int | None = None, impl: str = "ref"):
     """One decode step over every lane of the paged (block-table) cache.
     Identical bookkeeping to :func:`slot_decode_program`; only the cache
     walk differs (``decode_step_paged`` through ``state["tables"]``)."""
@@ -248,7 +235,7 @@ def paged_decode_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
             state["tokens"], state["lengths"], state["tables"], impl=impl,
         )
 
-    return _decode_program(decode_fn, eos_id=eos_id, fused=fused)
+    return _decode_program(decode_fn, eos_id=eos_id)
 
 
 def paged_copy_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules):
@@ -269,16 +256,14 @@ def paged_copy_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules):
 
 
 def slot_prefill_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
-                         eos_id: int | None = None, fused: bool = True):
+                         eos_id: int | None = None):
     """Admit one prompt into lane ``slot``: prefill its KV into the lane
     (prompt padded to a length bucket; one executable per bucket), sample
     the first generated token, and seed the slot's scheduling state
     (including its per-slot sampling params).
 
     ``fn(params, state, prompt (1, bucket), slot, plen, limit, temp,
-    top_k, top_p) -> (state', tok (1,))`` with fused sampling, or
-    ``-> (state', logits)`` when ``fused=False`` (host samples and writes
-    tokens/active back).
+    top_k, top_p) -> (state', tok (1,))``, sampling on device.
 
     Family-generic like :func:`slot_decode_program`: ``mod.prefill_slot``
     writes a KV lane slice (lm), a per-lane recurrent snapshot at
@@ -322,12 +307,6 @@ def slot_prefill_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
         # in that window must not zero the state the replay will advance.
         keep_self = jnp.arange(state["active"].shape[0]) == slot
         keep = state["replay"] | keep_self
-        if not fused:
-            new_state["active"] = upd(state["active"], plen < limit)
-            if rec:
-                new_state["cache"] = rec.freeze(
-                    new_state["cache"], new_state["active"] | keep)
-            return new_state, logits
         tok = sample_tokens(
             logits, sub, jnp.reshape(temp, (1,)),
             top_ks=jnp.reshape(top_k, (1,)), top_ps=jnp.reshape(top_p, (1,)),
@@ -349,8 +328,7 @@ def slot_prefill_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
 
 
 def paged_prefill_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
-                          eos_id: int | None = None, fused: bool = True,
-                          first: bool = True):
+                          eos_id: int | None = None, first: bool = True):
     """Process ONE prefill chunk of a request in lane ``slot`` of the
     paged cache — chunked prefill's unit of work, also the whole-prompt
     admission when the chunk is the full bucket.
@@ -397,10 +375,6 @@ def paged_prefill_program(cfg: ArchConfig, mesh: Mesh, rules: ShardRules, *,
             "top_ps": upd(state["top_ps"], top_p),
             "key": key,
         }
-        if not fused:
-            new_state["active"] = upd(
-                state["active"], is_last & (plen < limit))
-            return new_state, logits
         tok = sample_tokens(
             logits, sub, jnp.reshape(temp, (1,)),
             top_ks=jnp.reshape(top_k, (1,)), top_ps=jnp.reshape(top_p, (1,)),

@@ -323,6 +323,59 @@ def check_decode_seq_sharded():
             np.testing.assert_array_equal(g, w)
 
 
+def check_serve_dp_sharded():
+    """The serve engine on an (8, 1) data mesh (lanes over data, the paged
+    pool's block dim over data) emits the single-device engine's greedy
+    tokens on a staggered stream: slotted, paged with preemption, and the
+    hybrid family. After prebuild the stream builds nothing."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import local_mesh, single_device_mesh
+    from repro.models import registry
+    from repro.models.common import ShardRules
+    from repro.serve import EngineConfig, ServeEngine
+
+    one, dp = single_device_mesh(), local_mesh()
+    assert dp.shape["data"] == 8, dp.shape
+    # 8 lanes of 32 at page 8 need up to 32 blocks; 16 forces preemption
+    preempt = dict(kv_layout="paged", page_size=8, num_blocks=16,
+                   admission="preempt")
+    rng = np.random.default_rng(0)
+    for arch, modes in (("smollm-360m", ({}, preempt)),
+                        ("zamba2-1.2b", ({},))):
+        cfg = dataclasses.replace(get_smoke_config(arch),
+                                  compute_dtype="float32")
+        params = registry.get_module(cfg).init(cfg, jax.random.PRNGKey(0))
+        # more requests than lanes, so lanes are reused mid-stream
+        prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32)
+                   for n in rng.integers(2, 15, 19)]
+        budgets = [int(b) for b in rng.integers(3, 12, 19)]
+
+        def drive(mesh, **extra):
+            eng = ServeEngine(cfg, mesh, ShardRules.for_mesh(mesh), params,
+                              EngineConfig(max_slots=8, max_len=32, **extra))
+            eng.prebuild()
+            builds = eng.stats["builds"]
+            rids = [eng.submit(p, max_new_tokens=b)
+                    for p, b in zip(prompts, budgets)]
+            while eng.has_work():
+                eng.step()
+                eng.check_invariants()
+            assert eng.stats["builds"] == builds, (arch, extra)
+            return [list(eng.completions[r].tokens) for r in rids], eng
+
+        want, _ = drive(one)
+        for extra in modes:
+            got, eng = drive(dp, **extra)
+            assert got == want, (arch, extra)
+            if extra.get("admission") == "preempt":
+                assert eng.counters["preemptions"] > 0
+                assert eng.alloc.in_use == 0
+
+
 CHECKS = {
     "scatter_reduce": check_scatter_reduce,
     "indexing": check_indexing,
@@ -333,6 +386,7 @@ CHECKS = {
     "bucketed_reduce": check_bucketed_reduce,
     "flat_parity": check_flat_parity,
     "decode_seq_sharded": check_decode_seq_sharded,
+    "serve_dp_sharded": check_serve_dp_sharded,
 }
 
 

@@ -41,6 +41,13 @@ def test_seq_sharded_pos_minor_decode():
     run_check("decode_seq_sharded")
 
 
+def test_serve_engine_dp_sharded():
+    """The serve engine on an 8-way data mesh (slotted, paged with
+    preemption, hybrid) matches the single-device engine's greedy tokens,
+    and builds nothing after prebuild."""
+    run_check("serve_dp_sharded")
+
+
 def test_elastic_restore():
     """Checkpoint from a dp=8 mesh restores and trains on a dp=4xtp=2 mesh."""
     run_check("elastic")

@@ -511,6 +511,7 @@ def test_chaos_fuzz(setup):
     agg = {"faults_injected": 0, "faults_detected": 0, "retries": 0,
            "status_ok": 0, "status_timeout": 0, "status_cancelled": 0,
            "status_failed": 0}
+    recovered = 0
     for seed in range(CHAOS_EPISODES):
         rng = np.random.default_rng(9000 + seed)
         stream = make_stream(rng, cfg.vocab)
@@ -542,6 +543,17 @@ def test_chaos_fuzz(setup):
             st = eng.stats
             for k in agg:
                 agg[k] += st[k]
+        # faults alone (no deadlines, no cancels) under a generous retry
+        # budget: every request recovers to "ok", bitwise
+        eng = drive_chaos(cfg, mesh, rules, params, aot,
+                          dataclasses.replace(MODES["preempt"], max_retries=8),
+                          stream, FaultPlan(10_000 + seed, CHAOS_RATES))
+        for rid in range(len(stream)):
+            c = eng.completions[rid]
+            assert c.status == "ok" and list(c.tokens) == want[rid], (
+                f"seed={seed} rid={rid}: faults-only request ended "
+                f"{c.status!r} ({c.error}) or diverged")
+        recovered += eng.stats["retries"]
     assert aot.stats["builds"] == builds0, (
         "chaos retries forced fresh compiles — the retry path must reuse "
         "prebuilt executables")
@@ -550,6 +562,7 @@ def test_chaos_fuzz(setup):
     assert agg["faults_detected"] > 0, "no sentinel ever detected"
     assert agg["retries"] > 0, "no lane ever retried"
     assert agg["status_ok"] > 0
+    assert recovered > 0, "no faults-only request ever retried"
     if CHAOS_EPISODES >= 4:
         assert agg["status_cancelled"] > 0, "no cancel landed"
 

@@ -325,6 +325,8 @@ def test_preempted_lane_restores_without_replay(setups):
     assert eng.counters["spills"] == 1 and eng.counters["restores"] == 1
     assert eng.counters["replayed_tokens"] == 0
     assert eng.counters["restored_bytes"] > 0
+    # the prompt prefilled once: the resume re-prefilled nothing
+    assert eng.counters["prefill_tokens"] == 12
 
 
 def test_slotted_lane_spill_round_trips_position_minor(setups):

@@ -342,7 +342,7 @@ def test_observer_child_isolates_metrics_shares_timeline():
 # ---------------------------------------------------------------------------
 
 
-def test_engine_trace_schema_with_preempt(setup):
+def test_engine_trace_schema_with_preempt(setup, tmp_path):
     cfg, mesh, rules, params, aot = setup
     clock = FakeClock()
     obs = Observer.full(clock=clock, name="engine")
@@ -368,6 +368,13 @@ def test_engine_trace_schema_with_preempt(setup):
     assert info["requests"] == len(rids)
     assert info["terminals"] == len(rids)       # drained: all terminal
     assert info["spans"] > 0                    # decode/prefill spans ran
+    # the engine's stream survives both exports: JSONL round trip, and a
+    # non-empty Chrome trace
+    p = to_jsonl(obs.tracer.events, str(tmp_path / "engine.jsonl"))
+    assert validate(load_jsonl(p)) == info
+    doc = to_chrome_trace(obs.tracer.events, str(tmp_path / "engine.json"))
+    assert doc["traceEvents"]
+    assert json.load(open(tmp_path / "engine.json")) == doc
     for rid in rids:
         names = [e["name"] for e in request_timeline(obs.tracer.events, rid)]
         assert names[0] == "submit" and names[-1] == "terminal"
@@ -465,6 +472,8 @@ def test_traced_drive_is_bitwise_and_builds_flat(setup):
         "number of decode steps"
     assert aot.stats["builds"] == builds0, \
         "tracing forced fresh executable builds"
+    # the armed observer's metrics snapshot counts the same decode steps
+    assert obs.metrics.snapshot()["decode_steps"]["value"] == got[1] > 0
     validate(obs.tracer.events)
     _check_host_phases(obs.tracer.events)
 
@@ -607,12 +616,6 @@ def test_aot_build_timing_and_top_builds():
     assert aot.stats == {"builds": 2, "cache_hits": 1}
     assert set(aot.build_seconds) == {"slow", "fast"}
     assert all(s >= 0.0 for s in aot.build_seconds.values())
-    assert aot.build_s_total == pytest.approx(
-        sum(aot.build_seconds.values()))
-    top = aot.top_builds(5)
-    assert len(top) == 2
-    assert [k for k, _ in top] == sorted(
-        aot.build_seconds, key=aot.build_seconds.get, reverse=True)
     # each miss emitted one balanced aot_build span on the cache's track
     info = validate(obs.tracer.events)
     assert info["spans"] == 2

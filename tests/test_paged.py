@@ -430,19 +430,24 @@ def test_paged_block_budget_gates_admission(setup):
         tiny.submit(np.arange(8), max_new_tokens=6)   # needs 4 blocks
 
 
-def test_paged_engine_steady_builds_flat(setup):
-    """Steady state on the paged path may not build executables — chunked
-    prefill must not reintroduce per-length compiles."""
+@pytest.mark.parametrize("kv_layout", ["slotted", "paged"])
+def test_engine_steady_builds_flat(setup, kv_layout):
+    """Steady state may not build executables on either layout — once
+    warm, decode and admission dispatch purely from the AOT cache, and on
+    the paged path chunked prefill must not reintroduce per-length
+    compiles."""
     cfg, mesh, rules, params = setup
     rng = np.random.default_rng(5)
+    paged = dict(kv_layout="paged", page_size=8, prefill_chunk=4)
     eng = ServeEngine(
         cfg, mesh, rules, params,
-        EngineConfig(max_slots=2, max_len=32, kv_layout="paged",
-                     page_size=8, prefill_chunk=4))
+        EngineConfig(max_slots=2, max_len=32,
+                     **(paged if kv_layout == "paged" else {})))
     eng.run(_prompts(cfg, rng, [3, 9, 14]), max_new_tokens=3)
     builds = eng.stats["builds"]
-    # decode + first-chunk + continuation-chunk executables, nothing else
-    assert builds == 3
+    # slotted: decode + prefill@16; paged: decode + first-chunk +
+    # continuation-chunk executables; nothing else
+    assert builds == (3 if kv_layout == "paged" else 2)
     eng.run(_prompts(cfg, rng, [5, 13, 7, 2]), max_new_tokens=4)
     assert eng.stats["builds"] == builds
 

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_smoke_config
 from repro.core.aot import AotCache
@@ -21,7 +22,11 @@ from repro.serve import (
     generate,
     generate_static,
     prompt_buckets,
+    slot_decode_program,
+    slot_prefill_program,
+    slot_state_specs,
 )
+from repro.train.step import shardings_for
 
 
 @pytest.fixture(scope="module")
@@ -415,35 +420,34 @@ def test_submit_validation(setup):
         eng.submit(np.array([], np.int32))
 
 
-def test_host_vs_fused_sampler_parity(setup):
-    """The host-sampling ablation now carries full per-request sampling
-    (temperature + top-k + top-p): it draws from a host mirror of the
-    device key stream and runs the same ``sample_tokens`` math, so at a
-    fixed engine seed it reproduces the fused path token-for-token —
-    including stochastic lanes."""
-    cfg, mesh, rules, params = setup
-    rng = np.random.default_rng(9)
-    prompts = _prompts(cfg, rng, [6, 9, 4])
-    samplers = [dict(temperature=1.5, top_k=8),
-                dict(temperature=2.0, top_p=0.9),
-                dict(temperature=0.0)]           # greedy lane rides along
-
-    def run(fused):
-        eng = ServeEngine(cfg, mesh, rules, params,
-                          EngineConfig(max_slots=2, max_len=32, seed=3,
-                                       fused_sampling=fused))
-        rids = [eng.submit(p, max_new_tokens=5, **kw)
-                for p, kw in zip(prompts, samplers)]
-        eng.drain()
-        return [eng.completions[r].tokens for r in rids]
-
-    fused, host = run(True), run(False)
-    assert fused == host
-    # the stochastic lanes really sampled (not all-greedy degenerate)
-    solo = ServeEngine(cfg, mesh, rules, params,
-                       EngineConfig(max_slots=2, max_len=32, seed=4))
-    greedy = [list(t) for t in solo.run(prompts[:2], max_new_tokens=5)]
-    assert fused[:2] != greedy
+@pytest.mark.parametrize("fixture", ["setup", "hyb_setup"])
+def test_slot_programs_return_sampled_tokens(fixture, request):
+    """The slot decode and prefill programs, lowered as the engine lowers
+    them, sample on device: each returns ``(state', tok)`` where ``state'``
+    has exactly the input state's leaves, shapes and dtypes and ``tok`` is
+    int32 — ``(max_slots,)`` from decode, ``(1,)`` from prefill.  No
+    logits leave either program."""
+    cfg, mesh, rules, _ = request.getfixturevalue(fixture)
+    max_slots, rep = 4, NamedSharding(mesh, P())
+    p_sds = registry.abstract_params(cfg)
+    p_sh = shardings_for(mesh, registry.param_pspecs(cfg, rules))
+    s_sds, s_sh = slot_state_specs(cfg, mesh, max_slots, 32)
+    i32 = lambda shape=(): jax.ShapeDtypeStruct(shape, jnp.int32)
+    f32 = jax.ShapeDtypeStruct((), jnp.float32)
+    decode = jax.jit(
+        slot_decode_program(cfg, mesh, rules, eos_id=2),
+        in_shardings=(p_sh, s_sh), out_shardings=(s_sh, rep),
+        donate_argnums=(1,)).lower(p_sds, s_sds)
+    prefill = jax.jit(
+        slot_prefill_program(cfg, mesh, rules, eos_id=2),
+        in_shardings=(p_sh, s_sh) + (rep,) * 7, out_shardings=(s_sh, rep),
+        donate_argnums=(1,)).lower(
+            p_sds, s_sds, i32((1, 16)), i32(), i32(), i32(), f32, i32(), f32)
+    aval = lambda t: jax.tree.map(lambda a: (a.shape, a.dtype), t)
+    for lowered, n in ((decode, max_slots), (prefill, 1)):
+        state, tok = lowered.out_info
+        assert aval(state) == aval(s_sds)
+        assert (tok.shape, tok.dtype) == ((n,), jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -493,6 +497,12 @@ def test_hybrid_staggered_matches_solo_static(hyb_setup):
     # the composed cache really holds both kinds
     assert set(eng.rec.leaf_axes) == {"ssm", "conv"}
     assert "k" in eng.state["cache"] and "v" in eng.state["cache"]
+    # steady state: more admissions in the warm bucket build nothing
+    builds = eng.stats["builds"]
+    cfg = hyb_setup[0]
+    eng.run(_prompts(cfg, np.random.default_rng(2), [3, 12, 6]),
+            max_new_tokens=3)
+    assert eng.stats["builds"] == builds
 
 
 @pytest.mark.parametrize("fixture", ["rec_setup", "hyb_setup"])

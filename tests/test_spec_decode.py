@@ -70,10 +70,6 @@ def test_spec_config_validation(setup):
     with pytest.raises(ValueError, match="spec_draft"):
         ServeEngine(cfg, mesh, rules, params,
                     EngineConfig(**base, spec_k=3), aot=aot)
-    with pytest.raises(ValueError, match="fused_sampling"):
-        ServeEngine(cfg, mesh, rules, params,
-                    EngineConfig(**base, spec_draft=cfg, spec_k=3,
-                                 fused_sampling=False), aot=aot)
     with pytest.raises(ValueError, match="vocab"):
         bad = dataclasses.replace(cfg, vocab=cfg.vocab + 64)
         ServeEngine(cfg, mesh, rules, params,
@@ -205,8 +201,10 @@ def test_kv_truncate_on_reject_conserves_pool(setup):
 def test_spec_counters_and_stats(setup):
     """Counter accounting on a clean run: every non-replay verify round
     commits at least one token (the target's sample for the pending
-    position), so committed/lane-rounds >= 1.0; the acceptance rate is a
-    valid ratio; and a non-spec engine reports zeroed spec stats."""
+    position), and with a draft close to the target both halves of the
+    accept rule fire and committed/lane-rounds exceeds 1.0; the
+    acceptance rate is a valid ratio; and a non-spec engine reports
+    zeroed spec stats."""
     cfg, mesh, rules, params, dparams, aot = setup
     stream = make_stream(np.random.default_rng(43), cfg.vocab)
     _, eng = drive(cfg, mesh, rules, params, aot,
@@ -214,7 +212,10 @@ def test_spec_counters_and_stats(setup):
                    draft_params=dparams)
     st_ = eng.stats
     assert eng.counters["spec_rounds"] > 0
-    assert st_["tokens_per_decode_dispatch"] >= 1.0
+    assert eng.counters["spec_accepted"] > 0
+    assert eng.counters["spec_rejected"] > 0
+    # speculation pays for its verify dispatches
+    assert st_["tokens_per_decode_dispatch"] > 1.0
     assert 0.0 <= st_["spec_acceptance_rate"] <= 1.0
     assert st_["spec_acceptance_rate"] == pytest.approx(
         eng.counters["spec_accepted"] / max(1, eng.counters["spec_drafted"]))
